@@ -1,0 +1,7 @@
+"""stepper.ms_per_event: host milliseconds of the window per loop
+iteration of the stepper."""
+
+
+def read(run):
+    events = sum(a.events for a in run.answers)
+    return 1e3 * run.window_s / events if events > 0 else None
