@@ -207,6 +207,16 @@ steals "would further penalize the global strategy". Totals accumulate in an
 exact 62-bit integer (a pair of int32 lanes with explicit carry — JAX's
 default int64-disabled mode would silently truncate) so long runs never lose
 congestion counts to float32 rounding.
+
+Profiling: the loop names its layers for `jax.profiler`. Device ops carry
+`jax.named_scope`s in their `op_name` metadata — `stepper.tick` (children
+`tick.linkstate`, `tick.preshed`, `tick.arrivals`, `tick.grant`,
+`tick.commit`, `tick.recorder`), `stepper.leap` and `stepper.famine`.
+The entry points write host spans on the profiler's clock: `simulate` (or
+`simulate_batch` / `simulate_sweep`) over `simulate.prepare`, `.dispatch`,
+`.wait`, `.fetch` and `.finalize`. The root span's arguments carry the
+loop's exact `StepCounts`, summed over the call's points. With the
+profiler off the scopes are metadata and the spans record nothing.
 """
 
 from __future__ import annotations
@@ -428,6 +438,21 @@ class SimState(NamedTuple):
     arr_done: jax.Array     # () int32 requests completed (popped & served)
     soj_lo: jax.Array       # () int32 Σ sojourn ticks, low 30-bit lane
     soj_hi: jax.Array       # () int32 Σ sojourn ticks, carry lane
+
+
+class StepCounts(NamedTuple):
+    """How the loop spent its ticks, counted in the loop carry: every
+    iteration runs one tick in `tick_fn`; a famine replay then runs
+    `famine_ticks` more, and the leap skips `leap_ticks`. Exact, with
+    iterations + leap_ticks + famine_ticks == ticks. Under vmap
+    (`simulate_batch`, `simulate_sweep`) the replay's `lax.cond` becomes a
+    select, so the scan runs on every iteration: the replay steps executed
+    are then iterations × `famine_batch`, not `famine_replays` × it."""
+    iterations: jax.Array      # () int32 loop iterations (SimResult.events)
+    leap_ticks: jax.Array      # () int32 ticks skipped by the leap
+    famine_ticks: jax.Array    # () int32 ticks replayed by `famine_ff`
+    famine_replays: jax.Array  # () int32 iterations whose famine window
+                               # was certified
 
 
 class SimResult(NamedTuple):
@@ -1128,16 +1153,19 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         if ls is None:
             eidx, sp, link = None, speed, None
         else:
-            eidx, sp = _epoch_view(ls, t)
-            link = (ls.link_up[eidx], ls.link_tau[eidx],
-                    _masked_radius2(tbl, ls, eidx))
+            with jax.named_scope("tick.linkstate"):
+                eidx, sp = _epoch_view(ls, t)
+                link = (ls.link_up[eidx], ls.link_tau[eidx],
+                        _masked_radius2(tbl, ls, eidx))
 
         # ------------- scheduled failures / shutdowns --------------------- #
         # periodic schedules fire at base + k·period (one-shot: base == t)
         dying_now = alive & _fires_now(fail_time, fail_period, t)
-        warned = (alive & _fires_now(fail_time, fail_period,
-                                     t + p.warn_ticks)
-                  if cfg.preshed else jnp.zeros((W,), bool))
+        warned = jnp.zeros((W,), bool)
+        if cfg.preshed:
+            with jax.named_scope("tick.preshed"):
+                warned = alive & _fires_now(fail_time, fail_period,
+                                            t + p.warn_ticks)
 
         # every deque mutation below goes through the session: the staged
         # backend accumulates them into one end-of-tick apply, the loop
@@ -1150,16 +1178,20 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         # delivered in between. Retired workers stop stealing (see below).
         acc, overflow = state.acc, state.overflow
         if cfg.preshed:
-            heir = _nearest_alive_neighbor(tbl, alive & ~warned & ~dying_now,
-                                           jnp.arange(W))
-            acc, overflow = ses.transplant(acc, warned, heir, overflow)
-            # death-tick flush: bank in-flight loot into own deque, then move all
-            flush = dying_now
-            want_bank = flush & state.got
-            banked = ses.push(state.loot, want_bank)
-            overflow = overflow + (want_bank & ~banked).astype(jnp.int32)
-            acc, overflow = ses.transplant(acc, flush, heir, overflow)
-            state = state._replace(got=jnp.where(flush, False, state.got))
+            with jax.named_scope("tick.preshed"):
+                heir = _nearest_alive_neighbor(
+                    tbl, alive & ~warned & ~dying_now, jnp.arange(W))
+                acc, overflow = ses.transplant(acc, warned, heir, overflow)
+                # death-tick flush: bank in-flight loot into own deque,
+                # then move all
+                flush = dying_now
+                want_bank = flush & state.got
+                banked = ses.push(state.loot, want_bank)
+                overflow = overflow + (want_bank
+                                       & ~banked).astype(jnp.int32)
+                acc, overflow = ses.transplant(acc, flush, heir, overflow)
+                state = state._replace(
+                    got=jnp.where(flush, False, state.got))
 
         state = state._replace(acc=acc, overflow=overflow)
 
@@ -1280,7 +1312,8 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
             # ops commit here and a fresh session (back at the common-path
             # lane budget) carries the rest of the tick — TC ticks pay two
             # fused applies instead of one.
-            deq_mid = ses.finish()
+            with jax.named_scope("tick.commit"):
+                deq_mid = ses.finish()
             ses = _session(deq_mid, tasks.EXPAND_K + 1
                            + (arrivals.ARRIVAL_K if ar is not None else 0))
             state = state._replace(deque=deq_mid)
@@ -1298,47 +1331,52 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         # half-injected state) and before PHASE_RUN, so an idle station can
         # pop the fresh request in the same tick.
         if ar is not None:
-            a_fire = t == state.arr_t
-            a_station = arrivals.station_of(ar, aseed, state.arr_k)
-            a_accept = a_fire & arrivals.accepted(ar, aseed, state.arr_k, t)
-            # a dead station drops the uplink on the floor — counted in
-            # arr_dropped (and NOT pushed: work on a dead deque would leak
-            # into the liveness sum and the run could never drain)
-            a_live = a_accept & alive[a_station]
-            a_batch = jnp.clip(p.arrival_batch, 1, arrivals.ARRIVAL_K)
-            a_lanes = jnp.arange(arrivals.ARRIVAL_K, dtype=jnp.int32)
-            # task_id = arr_k·ARRIVAL_K + lane, wrapped into non-negative
-            # int32 (uniqueness wraps after 2^31 records — far beyond any
-            # max_ticks horizon at one candidate per tick)
-            a_ids = ((state.arr_k.astype(jnp.uint32)
-                      * jnp.uint32(arrivals.ARRIVAL_K)
-                      + a_lanes.astype(jnp.uint32))
-                     & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
-            a_recs = jnp.stack(
-                [jnp.full((arrivals.ARRIVAL_K,), tasks.KIND_REQ, jnp.int32),
-                 jnp.broadcast_to(ar.task_cost,
-                                  (arrivals.ARRIVAL_K,)).astype(jnp.int32),
-                 jnp.full((arrivals.ARRIVAL_K,), t, jnp.int32),
-                 a_ids], axis=1)
-            a_blk = jnp.zeros((W, arrivals.ARRIVAL_K, T),
-                              jnp.int32).at[a_station].set(a_recs)
-            a_counts = jnp.zeros((W,), jnp.int32).at[a_station].set(
-                jnp.where(a_live, a_batch, 0))
-            a_over = ses.push_many(a_blk, a_counts)
-            a_lost = (jnp.sum(a_over)
-                      + jnp.where(a_accept & ~alive[a_station], a_batch, 0))
-            # advance the cursor past the fired candidate — thinned and
-            # dead-station candidates cost one horizon visit too
-            # (conservative for the famine window, never wrong)
-            nxt = arrivals.gap_ticks(aseed, state.arr_k + 1,
-                                     p.arrival_gap_q8)
-            state = state._replace(
-                arr_t=jnp.where(a_fire, jnp.minimum(t + nxt, _NEVER),
-                                state.arr_t),
-                arr_k=state.arr_k + a_fire.astype(jnp.int32),
-                arr_injected=state.arr_injected + jnp.sum(a_counts - a_over),
-                arr_dropped=state.arr_dropped + a_lost,
-                overflow=state.overflow + a_over)
+            with jax.named_scope("tick.arrivals"):
+                a_fire = t == state.arr_t
+                a_station = arrivals.station_of(ar, aseed, state.arr_k)
+                a_accept = a_fire & arrivals.accepted(ar, aseed,
+                                                      state.arr_k, t)
+                # a dead station drops the uplink on the floor — counted
+                # in arr_dropped (and NOT pushed: work on a dead deque would
+                # leak into the liveness sum and the run could never drain)
+                a_live = a_accept & alive[a_station]
+                a_batch = jnp.clip(p.arrival_batch, 1, arrivals.ARRIVAL_K)
+                a_lanes = jnp.arange(arrivals.ARRIVAL_K, dtype=jnp.int32)
+                # task_id = arr_k·ARRIVAL_K + lane, wrapped into
+                # non-negative int32 (uniqueness wraps after 2^31 records —
+                # far beyond any max_ticks horizon at one candidate per tick)
+                a_ids = ((state.arr_k.astype(jnp.uint32)
+                          * jnp.uint32(arrivals.ARRIVAL_K)
+                          + a_lanes.astype(jnp.uint32))
+                         & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+                a_recs = jnp.stack(
+                    [jnp.full((arrivals.ARRIVAL_K,), tasks.KIND_REQ,
+                              jnp.int32),
+                     jnp.broadcast_to(ar.task_cost,
+                                      (arrivals.ARRIVAL_K,)).astype(jnp.int32),
+                     jnp.full((arrivals.ARRIVAL_K,), t, jnp.int32),
+                     a_ids], axis=1)
+                a_blk = jnp.zeros((W, arrivals.ARRIVAL_K, T),
+                                  jnp.int32).at[a_station].set(a_recs)
+                a_counts = jnp.zeros((W,), jnp.int32).at[a_station].set(
+                    jnp.where(a_live, a_batch, 0))
+                a_over = ses.push_many(a_blk, a_counts)
+                a_lost = (jnp.sum(a_over)
+                          + jnp.where(a_accept & ~alive[a_station],
+                                      a_batch, 0))
+                # advance the cursor past the fired candidate — thinned and
+                # dead-station candidates cost one horizon visit too
+                # (conservative for the famine window, never wrong)
+                nxt = arrivals.gap_ticks(aseed, state.arr_k + 1,
+                                         p.arrival_gap_q8)
+                state = state._replace(
+                    arr_t=jnp.where(a_fire, jnp.minimum(t + nxt, _NEVER),
+                                    state.arr_t),
+                    arr_k=state.arr_k + a_fire.astype(jnp.int32),
+                    arr_injected=(state.arr_injected
+                                  + jnp.sum(a_counts - a_over)),
+                    arr_dropped=state.arr_dropped + a_lost,
+                    overflow=state.overflow + a_over)
 
         # ------------- phase RUN: work / expand / start steal -------------- #
         active_tick = alive & (t % sp == 0)  # stragglers advance slowly
@@ -1419,12 +1457,14 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
             # reply below then prices as the nominal-RTT timeout.
             valid_victim = valid_victim & lstate.same_component(
                 ls, eidx, victim, jnp.arange(W))
-        plan = stealing.resolve_grants(jnp.where(valid_victim, victim, -1),
-                                       ses.size, p.max_grants_per_victim)
-        v = jnp.clip(plan.victim, 0, W - 1)
-        stolen_blk = ses.export(plan.taken, stealing.GRANT_WIDTH)
-        stolen = stolen_blk[v, jnp.clip(plan.rank, 0, stealing.GRANT_WIDTH - 1)]
-        got = plan.got
+        with jax.named_scope("tick.grant"):
+            plan = stealing.resolve_grants(jnp.where(valid_victim, victim, -1),
+                                           ses.size, p.max_grants_per_victim)
+            v = jnp.clip(plan.victim, 0, W - 1)
+            stolen_blk = ses.export(plan.taken, stealing.GRANT_WIDTH)
+            stolen = stolen_blk[v, jnp.clip(plan.rank, 0,
+                                            stealing.GRANT_WIDTH - 1)]
+            got = plan.got
         # victim-side steal ledger (who was stolen from, counted at grant)
         stolen_from = state.stolen_from + plan.taken
         # supervision: victims log (record, thief)
@@ -1484,7 +1524,8 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
 
         # the ONE fused commit of every staged deque mutation this tick
         # (loop backend: already-committed state, a no-op here)
-        deque_ = ses.finish()
+        with jax.named_scope("tick.commit"):
+            deque_ = ses.finish()
 
         # ------------- flight recorder: canonical per-tick emission -------- #
         # Fixed order (so leap-mode rings compare elementwise against the
@@ -1492,84 +1533,91 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         # resolutions, OVERFLOW, FAMINE transitions — then the tick's
         # time-series deltas against the entry state.
         if trc is not None:
-            warr = jnp.arange(W)
-            ep_lane = eidx if ls is not None else jnp.int32(0)
-            tr = tracing.emit(tr, trc, dying_now, tick=t,
-                              kind=tracing.EV_DEATH, worker=warr, victim=-1,
-                              epoch=ep_lane)
-            tr = tracing.emit(tr, trc, waking, tick=t, kind=tracing.EV_WAKE,
-                              worker=warr, victim=-1, epoch=ep_lane)
-            if ls is not None:
-                tr = tracing.emit1(
-                    tr, trc, (t > 0) & jnp.any(ls.epoch_starts == t),
-                    tick=t, kind=tracing.EV_EPOCH, epoch=ep_lane)
-                # a comp-gated draw never departs — but emit only for
-                # workers that COULD attempt under this epoch's link state:
-                # a fully victimless worker re-draws every oracle tick, and
-                # those ticks are provably eventless (the leap skips them;
-                # `_can_attempt` is the shared predicate, with the same
-                # fails row the draw itself saw)
-                can_try = _can_attempt(code, escalate_after, tbl, ls, eidx,
-                                       fails_sel, W)
-                no_live = idle & (victim_new >= 0) & ~reach & can_try
-                tr = tracing.emit(
-                    tr, trc, no_live, tick=t, kind=tracing.EV_NO_LIVE_VICTIM,
-                    worker=warr, victim=victim_new,
-                    hops=_hop_dist(mesh, tbl["coords"],
-                                   jnp.clip(victim_new, 0, W - 1)),
-                    epoch=ep_lane)
-            # open-loop ledger events: one ARRIVAL per record actually
-            # injected (task_id in the hops lane), one SOJOURN per
-            # completed request (inject tick in the victim lane, task_id
-            # in hops, priced sojourn in rtt) — both at deque-op ticks,
-            # which tick_fn executes in both step modes, so ring equality
-            # is inherited, not re-proven
-            if ar is not None:
-                a_ok = a_lanes < (a_counts[a_station] - a_over[a_station])
-                tr = tracing.emit(tr, trc, a_ok, tick=t,
-                                  kind=tracing.EV_ARRIVAL, worker=a_station,
-                                  victim=-1, hops=a_ids, epoch=ep_lane)
-                tr = tracing.emit(tr, trc, is_req, tick=t,
-                                  kind=tracing.EV_SOJOURN, worker=warr,
-                                  victim=task[:, 2], hops=task[:, 3],
-                                  rtt=soj, epoch=ep_lane)
-            # attempt resolution at request arrival: the request leg was
-            # banked in the (W,) req_ticks lane at departure, so the rtt
-            # lane prices the full round trip (incl. route-around detours)
-            req_lane = jnp.where(start_req, req_ticks, tr.req_ticks)
-            tr = tr._replace(req_ticks=req_lane)
-            kind_arr = jnp.where(
-                ~valid_victim, tracing.EV_SEVERED_DENIAL,
-                jnp.where(got, tracing.EV_GRANTED, tracing.EV_EMPTY_VICTIM))
-            tr = tracing.emit(tr, trc, arriving, tick=t, kind=kind_arr,
-                              worker=warr, victim=victim, hops=back_hops,
-                              rtt=req_lane + back_ticks, epoch=ep_lane)
-            # net per-tick overflow increase (a TC rollback can rewind the
-            # counter — the trace keeps the discarded timeline, so only
-            # fresh drops re-emit)
-            ovf_delta = overflow - st_in.overflow
-            tr = tracing.emit(tr, trc, ovf_delta > 0, tick=t,
-                              kind=tracing.EV_OVERFLOW, worker=warr,
-                              victim=-1, rtt=jnp.maximum(ovf_delta, 0),
-                              epoch=ep_lane)
-            # famine flag: end-of-tick total stealable supply == 0. Sizes
-            # only change at deque-op ticks — always tick_fn-executed in
-            # both modes — so the flag provably cannot toggle at skipped or
-            # replayed ticks.
-            famine_now = jnp.sum(deque_.size) == 0
-            tr = tracing.emit1(tr, trc, famine_now & ~tr.famine, tick=t,
-                               kind=tracing.EV_FAMINE_ENTER, epoch=ep_lane)
-            tr = tracing.emit1(tr, trc, ~famine_now & tr.famine, tick=t,
-                               kind=tracing.EV_FAMINE_EXIT, epoch=ep_lane)
-            tr = tr._replace(famine=famine_now)
-            tr = tracing.ts_add(
-                tr, trc, t,
-                busy=jnp.sum(busy) - jnp.sum(st_in.busy),
-                queue=jnp.sum(deque_.size),
-                inflight=jnp.sum(steal_wait) - jnp.sum(st_in.steal_wait),
-                attempts=jnp.sum(attempts) - jnp.sum(st_in.attempts),
-                successes=jnp.sum(successes) - jnp.sum(st_in.successes),
-                alive=jnp.sum(alive.astype(jnp.int32)))
+            with jax.named_scope("tick.recorder"):
+                warr = jnp.arange(W)
+                ep_lane = eidx if ls is not None else jnp.int32(0)
+                tr = tracing.emit(tr, trc, dying_now, tick=t,
+                                  kind=tracing.EV_DEATH, worker=warr,
+                                  victim=-1, epoch=ep_lane)
+                tr = tracing.emit(tr, trc, waking, tick=t,
+                                  kind=tracing.EV_WAKE, worker=warr,
+                                  victim=-1, epoch=ep_lane)
+                if ls is not None:
+                    tr = tracing.emit1(
+                        tr, trc, (t > 0) & jnp.any(ls.epoch_starts == t),
+                        tick=t, kind=tracing.EV_EPOCH, epoch=ep_lane)
+                    # a comp-gated draw never departs — but emit only for
+                    # workers that COULD attempt under this epoch's link
+                    # state: a fully victimless worker re-draws every oracle
+                    # tick, and those ticks are provably eventless (the leap
+                    # skips them; `_can_attempt` is the shared predicate,
+                    # with the same fails row the draw itself saw)
+                    can_try = _can_attempt(code, escalate_after, tbl, ls,
+                                           eidx, fails_sel, W)
+                    no_live = idle & (victim_new >= 0) & ~reach & can_try
+                    tr = tracing.emit(
+                        tr, trc, no_live, tick=t,
+                        kind=tracing.EV_NO_LIVE_VICTIM,
+                        worker=warr, victim=victim_new,
+                        hops=_hop_dist(mesh, tbl["coords"],
+                                       jnp.clip(victim_new, 0, W - 1)),
+                        epoch=ep_lane)
+                # open-loop ledger events: one ARRIVAL per record actually
+                # injected (task_id in the hops lane), one SOJOURN per
+                # completed request (inject tick in the victim lane,
+                # task_id in hops, priced sojourn in rtt) — both at deque-op
+                # ticks, which tick_fn executes in both step modes, so ring
+                # equality is inherited, not re-proven
+                if ar is not None:
+                    a_ok = a_lanes < (a_counts[a_station]
+                                      - a_over[a_station])
+                    tr = tracing.emit(tr, trc, a_ok, tick=t,
+                                      kind=tracing.EV_ARRIVAL,
+                                      worker=a_station, victim=-1,
+                                      hops=a_ids, epoch=ep_lane)
+                    tr = tracing.emit(tr, trc, is_req, tick=t,
+                                      kind=tracing.EV_SOJOURN, worker=warr,
+                                      victim=task[:, 2], hops=task[:, 3],
+                                      rtt=soj, epoch=ep_lane)
+                # attempt resolution at request arrival: the request leg
+                # was banked in the (W,) req_ticks lane at departure, so the
+                # rtt lane prices the full round trip (incl. route-around
+                # detours)
+                req_lane = jnp.where(start_req, req_ticks, tr.req_ticks)
+                tr = tr._replace(req_ticks=req_lane)
+                kind_arr = jnp.where(
+                    ~valid_victim, tracing.EV_SEVERED_DENIAL,
+                    jnp.where(got, tracing.EV_GRANTED,
+                              tracing.EV_EMPTY_VICTIM))
+                tr = tracing.emit(tr, trc, arriving, tick=t, kind=kind_arr,
+                                  worker=warr, victim=victim, hops=back_hops,
+                                  rtt=req_lane + back_ticks, epoch=ep_lane)
+                # net per-tick overflow increase (a TC rollback can rewind
+                # the counter — the trace keeps the discarded timeline, so
+                # only fresh drops re-emit)
+                ovf_delta = overflow - st_in.overflow
+                tr = tracing.emit(tr, trc, ovf_delta > 0, tick=t,
+                                  kind=tracing.EV_OVERFLOW, worker=warr,
+                                  victim=-1, rtt=jnp.maximum(ovf_delta, 0),
+                                  epoch=ep_lane)
+                # famine flag: end-of-tick total stealable supply == 0.
+                # Sizes only change at deque-op ticks — always
+                # tick_fn-executed in both modes — so the flag provably
+                # cannot toggle at skipped or replayed ticks.
+                famine_now = jnp.sum(deque_.size) == 0
+                tr = tracing.emit1(tr, trc, famine_now & ~tr.famine, tick=t,
+                                   kind=tracing.EV_FAMINE_ENTER, epoch=ep_lane)
+                tr = tracing.emit1(tr, trc, ~famine_now & tr.famine, tick=t,
+                                   kind=tracing.EV_FAMINE_EXIT, epoch=ep_lane)
+                tr = tr._replace(famine=famine_now)
+                tr = tracing.ts_add(
+                    tr, trc, t,
+                    busy=jnp.sum(busy) - jnp.sum(st_in.busy),
+                    queue=jnp.sum(deque_.size),
+                    inflight=jnp.sum(steal_wait) - jnp.sum(st_in.steal_wait),
+                    attempts=jnp.sum(attempts) - jnp.sum(st_in.attempts),
+                    successes=jnp.sum(successes) - jnp.sum(st_in.successes),
+                    alive=jnp.sum(alive.astype(jnp.int32)))
 
         new_state = state._replace(
             deque=deque_, acc=acc, work=work, fails=fails, phase=phase,
@@ -1651,9 +1699,10 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         machine, burn-downs, and stats. Victim draws are gathered from
         `stealing.batched_victim_draws`, which replays the exact
         ``fold_in(key0, t)``-keyed per-tick sequence, keeping the result
-        bit-identical to the one-tick oracle. Returns (state, t, live, ne)
-        with `ne` the `_next_event` horizon of the returned state, so the
-        trailing leap never recomputes it.
+        bit-identical to the one-tick oracle. Returns
+        (state, tr, t, live, ne, ran): `ne` is the `_next_event` horizon of
+        the returned state, so the trailing leap never recomputes it, and
+        `ran` says whether the replay ran.
         """
         ne_risky = _famine_horizon(state, t, speed, fail_time, wake_time,
                                    fail_period, cfg, p, W, mesh, tbl, ls, ar)
@@ -1846,30 +1895,47 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
                 new_state, t_out, speed, fail_time, wake_time, fail_period,
                 cfg, p, W, tbl, ls, ar)
 
-        return jax.lax.cond(pred, fast,
-                            lambda s, r, tt, lv: (s, r, tt, lv, ne_all),
-                            state, tr, t, live)
+        state, tr, t, live, ne = jax.lax.cond(
+            pred, fast, lambda s, r, tt, lv: (s, r, tt, lv, ne_all),
+            state, tr, t, live)
+        return state, tr, t, live, ne, pred
 
     def cond(carry):
-        state, snap, tr, t, live, iters = carry
+        state, snap, tr, t, live, counts = carry
         return live & (t < cfg.max_ticks)
 
     def body(carry):
-        state, snap, tr, t, _, iters = carry
-        state, snap, tr, t, live = tick_fn((state, snap, tr, t))
+        state, snap, tr, t, _, counts = carry
+        with jax.named_scope("stepper.tick"):
+            state, snap, tr, t, live = tick_fn((state, snap, tr, t))
+        leap_ticks = famine_ticks = replayed = jnp.int32(0)
         if cfg.step_mode == "leap":
-            ne = _next_event(state, t, speed, fail_time, wake_time,
-                             fail_period, cfg, p, W, tbl, ls, ar)
+            with jax.named_scope("stepper.leap"):
+                ne = _next_event(state, t, speed, fail_time, wake_time,
+                                 fail_period, cfg, p, W, tbl, ls, ar)
             if famine_on:
-                state, tr, t, live, ne = famine_ff(state, tr, t, live, ne)
-            state, tr, t, live = leap(state, tr, t, live, ne)
-        return state, snap, tr, t, live, iters + 1
+                t_in = t
+                with jax.named_scope("stepper.famine"):
+                    state, tr, t, live, ne, ran = famine_ff(state, tr, t,
+                                                            live, ne)
+                famine_ticks = t - t_in
+                replayed = ran.astype(jnp.int32)
+            t_in = t
+            with jax.named_scope("stepper.leap"):
+                state, tr, t, live = leap(state, tr, t, live, ne)
+            leap_ticks = t - t_in
+        counts = StepCounts(counts.iterations + 1,
+                            counts.leap_ticks + leap_ticks,
+                            counts.famine_ticks + famine_ticks,
+                            counts.famine_replays + replayed)
+        return state, snap, tr, t, live, counts
 
     # non-TC modes don't carry the (W, C, T) snapshot copy through the loop
     snap0 = state0 if cfg.recovery == Recovery.TC else ()
-    state, _, tr, ticks, _, iters = jax.lax.while_loop(
-        cond, body, (state0, snap0, tr0, jnp.int32(0), jnp.bool_(True),
-                     jnp.int32(0)))
+    zero = jnp.int32(0)
+    state, _, tr, ticks, _, counts = jax.lax.while_loop(
+        cond, body, (state0, snap0, tr0, zero, jnp.bool_(True),
+                     StepCounts(zero, zero, zero, zero)))
     if trc is not None:
         # attempts still in their request flight when the run ended: both
         # step modes reach the identical final state, so the flush (and its
@@ -1884,7 +1950,7 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
             hops=_hop_dist(mesh, tbl["coords"],
                            jnp.clip(state.victim, 0, W - 1)),
             rtt=tr.req_ticks, epoch=ep_end)
-    return state, tr, ticks, iters
+    return state, tr, ticks, counts
 
 
 _sim_jit = partial(jax.jit, static_argnames=("workload", "mesh", "cfg"))(_sim_core)
@@ -1976,8 +2042,8 @@ def _ckpt_state_bytes(mesh: topo.MeshTopology, cfg: StaticConfig) -> int:
     return mesh.num_workers * cfg.capacity * 4 * 4 + mesh.num_workers * 4
 
 
-def _finalize(state, tr, ticks, iters, mesh: topo.MeshTopology,
-              cfg: StaticConfig) -> SimResult:
+def _finalize(state, tr, ticks, counts: StepCounts,
+              mesh: topo.MeshTopology, cfg: StaticConfig) -> SimResult:
     att, suc = int(state.attempts.sum()), int(state.successes.sum())
     busy = int(np.asarray(state.busy, np.int64).sum())
     t = int(ticks)
@@ -1998,7 +2064,7 @@ def _finalize(state, tr, ticks, iters, mesh: topo.MeshTopology,
         overflow=int(np.asarray(state.overflow, np.int64).sum()),
         utilization=busy / max(t * max(alive_n, 1), 1),
         per_worker_busy=np.asarray(state.busy),
-        events=int(iters),
+        events=int(counts.iterations),
         per_worker_overflow=np.asarray(state.overflow),
         per_worker_stolen=np.asarray(state.stolen_from),
         per_worker_hiwater=np.asarray(state.hiwater),
@@ -2011,6 +2077,43 @@ def _finalize(state, tr, ticks, iters, mesh: topo.MeshTopology,
         sojourn_sum_ticks=soj_sum,
         sojourn_mean=soj_sum / max(req_done, 1),
         sojourn=tracing.sojourn_stats(trace) if trace is not None else None)
+
+
+_span = jax.profiler.TraceAnnotation
+
+
+def _run_loop(root, dispatch, finalize, n_points: int, **args):
+    """The entry points' shared tail, one span each under the open `root`
+    span: dispatch the compiled loop (a cache lookup, or a retrace and
+    compile), wait for the device, fetch the outputs, and `finalize` the
+    first `n_points` of them. While the profiler records, `root` gets
+    `args` and the step counts summed over those points as its
+    arguments."""
+    with _span("simulate.dispatch"):
+        out = dispatch()
+    with _span("simulate.wait"):
+        out = jax.block_until_ready(out)
+    with _span("simulate.fetch"):
+        state, tr, ticks, counts = jax.device_get(out)
+    with _span("simulate.finalize"):
+        results = finalize(state, tr, ticks, counts)
+    if _span.is_enabled():
+        def total(x):
+            return int(np.asarray(x, np.int64).reshape(-1)[:n_points].sum())
+        root.set_metadata(ticks=total(ticks), **{
+            k: total(v) for k, v in counts._asdict().items()}, **args)
+    return results
+
+
+def _point_results(mesh, scfg, points: int):
+    """`finalize` of a batched call: one `SimResult` per point."""
+    def finalize(states, trs, ticks, counts):
+        return [
+            _finalize(jax.tree.map(lambda x: x[i], states),
+                      jax.tree.map(lambda x: x[i], trs), ticks[i],
+                      jax.tree.map(lambda x: x[i], counts), mesh, scfg)
+            for i in range(points)]
+    return finalize
 
 
 def _fail_speed_arrays(W, fail_time, speed, wake_time=None, fail_period=None):
@@ -2081,6 +2184,22 @@ def _arrival_tables(arr, mesh):
     return arrivals.device_tables(arr, mesh)
 
 
+def _prepare(mesh, cfg, fail_time=None, speed=None, linkstate=None,
+             wake_time=None, fail_period=None, routing_backend="auto",
+             arrivals=None):
+    """`simulate`'s checks and the loop's arguments after the workload and
+    mesh: the static config, the traced params, and the device arrays."""
+    cfg = cfg or SimConfig()
+    _check_cfg(cfg)
+    scfg, params = cfg.split()
+    _check_arrivals(arrivals, params)
+    ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
+    ar = _arrival_tables(arrivals, mesh)
+    ft, wt, fp, sp = _fail_speed_arrays(
+        mesh.num_workers, fail_time, speed, wake_time, fail_period)
+    return scfg, params, (ft, wt, fp, sp, ls, ar)
+
+
 def simulate(workload, mesh: topo.MeshTopology, cfg: SimConfig | None = None,
              fail_time: np.ndarray | None = None,
              speed: np.ndarray | None = None,
@@ -2107,18 +2226,26 @@ def simulate(workload, mesh: topo.MeshTopology, cfg: SimConfig | None = None,
     station workers at i.i.d. exponential gaps (mean `arrival_gap_q8/256`
     ticks, thinned by the per-epoch rate schedule and on/off bursts), and
     `SimResult` reports their sojourn percentiles."""
-    cfg = cfg or SimConfig()
-    _check_cfg(cfg)
-    scfg, params = cfg.split()
-    _check_arrivals(arrivals, params)
-    ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
-    ar = _arrival_tables(arrivals, mesh)
-    ft, wt, fp, sp = _fail_speed_arrays(mesh.num_workers, fail_time, speed,
-                                        wake_time, fail_period)
-    state, tr, ticks, iters = _sim_jit(workload, mesh, scfg, params, ft, wt,
-                                       fp, sp, ls, ar)
-    state, tr = jax.device_get((state, tr))
-    return _finalize(state, tr, ticks, iters, mesh, scfg)
+    with _span("simulate") as root:
+        with _span("simulate.prepare"):
+            scfg, params, arrays = _prepare(
+                mesh, cfg, fail_time, speed, linkstate, wake_time,
+                fail_period, routing_backend, arrivals)
+        return _run_loop(
+            root,
+            lambda: _sim_jit(workload, mesh, scfg, params, *arrays),
+            lambda *out: _finalize(*out, mesh, scfg), 1,
+            seed=int(params.seed), workers=mesh.num_workers)
+
+
+def lower_simulate(workload, mesh: topo.MeshTopology,
+                   cfg: SimConfig | None = None, **kwargs):
+    """The `jax.stages.Lowered` loop that `simulate(workload, mesh, cfg,
+    **kwargs)` runs. Its `.compile().as_text()` is the program the device
+    runs, each op with the `op_name` path of its scopes: what a profile's
+    op names mean in the loop's layers."""
+    scfg, params, arrays = _prepare(mesh, cfg, **kwargs)
+    return _sim_jit.lower(workload, mesh, scfg, params, *arrays)
 
 
 def simulate_batch(workload, mesh: topo.MeshTopology,
@@ -2139,31 +2266,30 @@ def simulate_batch(workload, mesh: topo.MeshTopology,
     one `SimResult` per seed, identical to
     `simulate(..., cfg._replace-ish(seed=s))` run serially.
     """
-    cfg = cfg or SimConfig()
-    _check_cfg(cfg)
-    scfg, params = cfg.split()
-    _check_arrivals(arrivals, params)
-    ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
-    ar = _arrival_tables(arrivals, mesh)
-    W = mesh.num_workers
-    seeds = list(seeds)
-    pstack = stack_params([params._replace(seed=int(s)) for s in seeds])
-    ft, wt, fp, sp = _fail_speed_arrays(W, fail_time, speed, wake_time,
-                                        fail_period)
-    B = len(seeds)
-    fts = jnp.broadcast_to(ft[None], (B, W))
-    wts = jnp.broadcast_to(wt[None], (B, W))
-    fps = jnp.broadcast_to(fp[None], (B, W))
-    sps = jnp.broadcast_to(sp[None], (B, W))
-    states, trs, ticks, iters = _sim_batch_jit(workload, mesh, scfg, pstack,
-                                               fts, wts, fps, sps, ls, ar)
-    states, trs, ticks, iters = jax.device_get((states, trs, ticks, iters))
-    return [
-        _finalize(jax.tree.map(lambda x: x[i], states),
-                  jax.tree.map(lambda x: x[i], trs), ticks[i], iters[i],
-                  mesh, scfg)
-        for i in range(B)
-    ]
+    with _span("simulate_batch") as root:
+        with _span("simulate.prepare"):
+            cfg = cfg or SimConfig()
+            _check_cfg(cfg)
+            scfg, params = cfg.split()
+            _check_arrivals(arrivals, params)
+            ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
+            ar = _arrival_tables(arrivals, mesh)
+            W = mesh.num_workers
+            seeds = list(seeds)
+            pstack = stack_params([params._replace(seed=int(s))
+                                   for s in seeds])
+            ft, wt, fp, sp = _fail_speed_arrays(W, fail_time, speed,
+                                                wake_time, fail_period)
+            B = len(seeds)
+            fts = jnp.broadcast_to(ft[None], (B, W))
+            wts = jnp.broadcast_to(wt[None], (B, W))
+            fps = jnp.broadcast_to(fp[None], (B, W))
+            sps = jnp.broadcast_to(sp[None], (B, W))
+        return _run_loop(
+            root,
+            lambda: _sim_batch_jit(workload, mesh, scfg, pstack, fts, wts,
+                                   fps, sps, ls, ar),
+            _point_results(mesh, scfg, B), B, points=B, workers=W)
 
 
 def simulate_sweep(workload, mesh: topo.MeshTopology, cfg,
@@ -2200,39 +2326,33 @@ def simulate_sweep(workload, mesh: topo.MeshTopology, cfg,
     pts = [p.params if isinstance(p, SimConfig) else p for p in params_list]
     if not pts:
         return []
-    for p in pts:
-        _check_params(p)
-    if scfg.trace is not None:
-        scfg.trace.validate()
-    G = len(pts)
-    W = mesh.num_workers
-    ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
-    for p in pts:
-        _check_arrivals(arrivals, p)
-    ar = _arrival_tables(arrivals, mesh)
-    ft, wt, fp, sp = _fail_speed_arrays(W, fail_time, speed, wake_time,
-                                        fail_period)
-    devs = tuple(devices) if devices is not None else tuple(jax.local_devices())
-    sharded = len(devs) > 1
-    if sharded:  # pad the grid to a device multiple (trimmed below)
-        pts = pts + [pts[-1]] * ((-G) % len(devs))
-    pstack = stack_params(pts)
-    B = len(pts)
-    fts = jnp.broadcast_to(ft[None], (B, W))
-    wts = jnp.broadcast_to(wt[None], (B, W))
-    fps = jnp.broadcast_to(fp[None], (B, W))
-    sps = jnp.broadcast_to(sp[None], (B, W))
-    if sharded:
-        fn = _sharded_sweep_fn(workload, mesh, scfg, devs)
-        states, trs, ticks, iters = fn(pstack, fts, wts, fps, sps, ls, ar)
-    else:
-        states, trs, ticks, iters = _sim_batch_jit(workload, mesh, scfg,
-                                                   pstack, fts, wts, fps,
-                                                   sps, ls, ar)
-    states, trs, ticks, iters = jax.device_get((states, trs, ticks, iters))
-    return [
-        _finalize(jax.tree.map(lambda x: x[i], states),
-                  jax.tree.map(lambda x: x[i], trs), ticks[i], iters[i],
-                  mesh, scfg)
-        for i in range(G)
-    ]
+    with _span("simulate_sweep") as root:
+        with _span("simulate.prepare"):
+            for p in pts:
+                _check_params(p)
+            if scfg.trace is not None:
+                scfg.trace.validate()
+            G = len(pts)
+            W = mesh.num_workers
+            ls = _linkstate_tables(linkstate, mesh, speed, routing_backend)
+            for p in pts:
+                _check_arrivals(arrivals, p)
+            ar = _arrival_tables(arrivals, mesh)
+            ft, wt, fp, sp = _fail_speed_arrays(W, fail_time, speed,
+                                                wake_time, fail_period)
+            devs = (tuple(devices) if devices is not None
+                    else tuple(jax.local_devices()))
+            sharded = len(devs) > 1
+            if sharded:  # pad the grid to a device multiple (trimmed below)
+                pts = pts + [pts[-1]] * ((-G) % len(devs))
+            pstack = stack_params(pts)
+            B = len(pts)
+            fts = jnp.broadcast_to(ft[None], (B, W))
+            wts = jnp.broadcast_to(wt[None], (B, W))
+            fps = jnp.broadcast_to(fp[None], (B, W))
+            sps = jnp.broadcast_to(sp[None], (B, W))
+            fn = (_sharded_sweep_fn(workload, mesh, scfg, devs) if sharded
+                  else partial(_sim_batch_jit, workload, mesh, scfg))
+        return _run_loop(
+            root, lambda: fn(pstack, fts, wts, fps, sps, ls, ar),
+            _point_results(mesh, scfg, G), G, points=G, workers=W)

@@ -8,9 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import constellation, linkstate
+from repro.core import arrivals, constellation, linkstate
 from repro.core import deque as dq
-from repro.core import simulator, stealing, tasks, topology
+from repro.core import simulator, stealing, tasks, topology, tracing
 
 FIB = tasks.FibWorkload(n=24, cutoff=10, max_leaf_cost=8)
 MESH = topology.MeshTopology.square(16)
@@ -1176,3 +1176,118 @@ def test_leap_equals_tick_under_sparse_backend(strategy, scenario, tau):
     assert (a.per_worker_busy == b.per_worker_busy).all()
     assert (a.per_worker_overflow == b.per_worker_overflow).all()
     assert (a.per_worker_stolen == b.per_worker_stolen).all()
+
+
+# --------------------------------------------------------------------------- #
+# Step counters and the entry points' profiler spans
+# --------------------------------------------------------------------------- #
+COUNT_ARRIVALS = arrivals.ArrivalConfig(task_cost=5, num_stations=3)
+
+
+def _count_case(strategy, mode, dynamic):
+    """`SimConfig` and `simulate` arguments of one counter case: a static
+    mesh, or link state, pre-shed, open-loop arrivals and the recorder."""
+    cfg = simulator.SimConfig(strategy=strategy, capacity=128,
+                              max_ticks=200_000, step_mode=mode)
+    if not dynamic:
+        return cfg, {}
+    ls, ft = _dynamic_schedule()
+    cfg = dataclasses.replace(
+        cfg, max_ticks=500, preshed=True, warn_ticks=8,
+        arrival_gap_q8=6 * 256, arrival_batch=2,
+        trace=tracing.TraceConfig(ring_capacity=4096, bins=16,
+                                  bin_ticks=32))
+    return cfg, dict(linkstate=ls, fail_time=ft, arrivals=COUNT_ARRIVALS)
+
+
+def _loop_args(cfg, linkstate=None, fail_time=None, arrivals=None):
+    """The compiled loop's arguments, as `simulate` prepares them."""
+    ft, wt, fp, sp = simulator._fail_speed_arrays(EQ_MESH.num_workers,
+                                                  fail_time, None)
+    return (ft, wt, fp, sp,
+            simulator._linkstate_tables(linkstate, EQ_MESH, None),
+            simulator._arrival_tables(arrivals, EQ_MESH))
+
+
+def _step_counts(cfg, **kw):
+    scfg, params = cfg.split()
+    _, _, ticks, counts = simulator._sim_jit(EQ_FIB, EQ_MESH, scfg, params,
+                                             *_loop_args(cfg, **kw))
+    return int(ticks), {k: int(v) for k, v in counts._asdict().items()}
+
+
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
+@pytest.mark.parametrize("mode", ["tick", "leap"])
+@pytest.mark.parametrize("strategy", [stealing.Strategy.NEIGHBOR,
+                                      stealing.Strategy.GLOBAL,
+                                      stealing.Strategy.ADAPTIVE])
+def test_step_counts_split_the_ticks(strategy, mode, dynamic):
+    """Every tick is a loop iteration's own tick, a leapt tick or a
+    replayed one; the tick stepper leaps and replays none; a replay runs
+    at most `famine_batch` ticks; and the iterations are `events`."""
+    cfg, kw = _count_case(strategy, mode, dynamic)
+    ticks, c = _step_counts(cfg, **kw)
+    r = simulator.simulate(EQ_FIB, EQ_MESH, cfg, **kw)
+    assert (ticks, c["iterations"]) == (r.ticks, r.events)
+    assert c["iterations"] + c["leap_ticks"] + c["famine_ticks"] == ticks
+    if mode == "tick":
+        assert c["leap_ticks"] == c["famine_ticks"] == 0
+        assert c["famine_replays"] == 0
+    assert c["famine_ticks"] <= cfg.famine_batch * c["famine_replays"]
+    assert c["famine_replays"] <= c["iterations"]
+
+
+def test_sweep_step_counts_equal_per_point_counts():
+    """The vmapped loop freezes a finished point's counters with its
+    state: each point of a sweep counts what its own `simulate` counts."""
+    cfg, kw = _count_case(stealing.Strategy.NEIGHBOR, "leap", True)
+    pts = [cfg.params._replace(strategy=stealing.strategy_code(s), seed=k)
+           for k, s in enumerate([stealing.Strategy.NEIGHBOR,
+                                  stealing.Strategy.GLOBAL,
+                                  stealing.Strategy.ADAPTIVE])]
+    B = len(pts)
+    ft, wt, fp, sp, ls, ar = _loop_args(cfg, **kw)
+    _, _, ticks, counts = simulator._sim_batch_jit(
+        EQ_FIB, EQ_MESH, cfg.static, simulator.stack_params(pts),
+        *(jnp.broadcast_to(x[None], (B,) + x.shape) for x in (ft, wt, fp,
+                                                             sp)), ls, ar)
+    for i, p in enumerate(pts):
+        one = dataclasses.replace(cfg, strategy=stealing.CODE_STRATEGIES[
+            int(p.strategy)], seed=int(p.seed))
+        want_ticks, want = _step_counts(one, **kw)
+        assert int(ticks[i]) == want_ticks
+        assert {k: int(v[i]) for k, v in counts._asdict().items()} == want
+
+
+def test_simulate_span_carries_step_counts(tmp_path):
+    """Under the profiler, `simulate` writes its span with the step
+    counters as arguments, and its five children nest inside it in
+    order."""
+    import jax
+
+    cfg, kw = _count_case(stealing.Strategy.NEIGHBOR, "leap", False)
+    simulator.simulate(EQ_FIB, EQ_MESH, cfg, **kw)   # compile outside
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1                       # spans, no CPU ops
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    r = simulator.simulate(EQ_FIB, EQ_MESH, cfg, **kw)
+    jax.profiler.stop_trace()
+    path, = tmp_path.rglob("*.xplane.pb")
+    spans = sorted(
+        ([ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+          dict(ev.stats)]
+         for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+         for line in plane.lines for ev in line.events
+         if ev.name.startswith("simulate")), key=lambda s: s[1])
+    (_, s0, e0, args), *children = spans
+    ticks, counts = _step_counts(cfg, **kw)
+    assert args == {"seed": cfg.seed, "workers": EQ_MESH.num_workers,
+                    "ticks": ticks, **counts}
+    assert args["ticks"] == r.ticks and args["iterations"] == r.events
+    assert [c[0] for c in children] == [
+        "simulate.prepare", "simulate.dispatch", "simulate.wait",
+        "simulate.fetch", "simulate.finalize"]
+    ends = [s0] + [c[2] for c in children]
+    for (_, s, e, _), prev_end in zip(children, ends):
+        assert prev_end <= s <= e <= e0

@@ -12,6 +12,7 @@ this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,3 +114,10 @@ def test_sim_step_compiles_for_v5e_with_both_kernels(one_chip,
     names = {name for name in ("steal_compact", "deque_apply")
              if any(name in ln for ln in calls)}
     assert names == {"steal_compact", "deque_apply"}, calls
+    # the chip's compiler keeps the loop's scopes in each op's metadata,
+    # which is how a device trace's op names map to the loop's layers
+    scopes = {part for path in re.findall(r'op_name="([^"]*)"', hlo)
+              for part in path.split("/")
+              if re.fullmatch(r"(stepper|tick)\.\w+", part)}
+    assert scopes == {"stepper.tick", "tick.grant", "tick.commit",
+                      "stepper.leap", "stepper.famine"}
