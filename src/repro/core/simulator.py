@@ -57,11 +57,14 @@ pop right after delivering), and the recovery / checkpoint / epoch
 horizons. Within that
 window deque sizes are provably frozen, so by induction every steal
 attempt fails deterministically; `famine_ff` then replays up to
-``famine_batch`` such ticks in ONE fused `lax.scan` per loop iteration —
-only the probe phase machine, burn-downs, and stats, no deque ops, no
-grant sort, no recovery machinery. Victim draws are gathered from
-`stealing.batched_victim_draws`, which replays the exact per-tick
-``fold_in(key0, t)`` sequence, so the result stays bit-identical.
+``famine_batch`` such ticks per loop iteration in a bounded
+`lax.while_loop` — only the probe phase machine, burn-downs, and stats,
+no deque ops, no grant sort, no recovery machinery. The loop stops at the
+window's end, or when the run goes idle, so it executes exactly the ticks
+it replays; under vmap it runs to the longest lane's window, never past
+``famine_batch``. Each step draws its tick's victims with
+`stealing.victim_draws_code`, the exact per-tick ``fold_in(key0, t)``
+sequence, so the result stays bit-identical.
 Measured effect (bench_sim_throughput, NEIGHBOR W=100): leap factor ~1× →
 ~7× at τ=5 and ~14× at τ=1. Note the leap factor depends on the
 famine-churn vs backlog regime, not just granularity: GLOBAL's thieves
@@ -272,8 +275,9 @@ class SimConfig:
     step_mode: str = "leap"
     # famine fast path (leap mode only): max ticks of deterministically
     # failing probe cycles collapsed into ONE loop iteration by a pruned
-    # batched replay (0 disables; bit-identical either way — the batch size
-    # only trades loop iterations against per-iteration work)
+    # replay that stops at the window's end (0 disables; bit-identical
+    # either way — the cap only trades loop iterations against replay
+    # steps)
     famine_batch: int = 64
     # Pallas kernels: the victim-side grant export (`steal_compact`, both
     # deque backends) and the staged-ops commit (`deque_apply`, staged
@@ -444,10 +448,13 @@ class StepCounts(NamedTuple):
     """How the loop spent its ticks, counted in the loop carry: every
     iteration runs one tick in `tick_fn`; a famine replay then runs
     `famine_ticks` more, and the leap skips `leap_ticks`. Exact, with
-    iterations + leap_ticks + famine_ticks == ticks. Under vmap
-    (`simulate_batch`, `simulate_sweep`) the replay's `lax.cond` becomes a
-    select, so the scan runs on every iteration: the replay steps executed
-    are then iterations × `famine_batch`, not `famine_replays` × it."""
+    iterations + leap_ticks + famine_ticks == ticks. For one point the
+    replay loop executes exactly `famine_ticks` steps, at most
+    `famine_batch` per replay. Under vmap (`simulate_batch`,
+    `simulate_sweep`) the replay's `lax.cond` becomes a select and the
+    points share one replay loop, which runs, on every iteration, to the
+    longest window among the points whose window was certified: those
+    executed steps are shared, and a point's own carry cannot see them."""
     iterations: jax.Array      # () int32 loop iterations (SimResult.events)
     leap_ticks: jax.Array      # () int32 ticks skipped by the leap
     famine_ticks: jax.Array    # () int32 ticks replayed by `famine_ff`
@@ -1073,7 +1080,10 @@ def trace_count() -> int:
 
 def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
               p: SimParams, fail_time, wake_time, fail_period, speed,
-              ls=None, ar=None):
+              ls=None, ar=None, points_axis=None):
+    """The device loop of one point. `points_axis` names the vmap axis
+    when the batched entry points run many points in one loop; the famine
+    replay then runs one trip count for all of them (see `famine_ff`)."""
     global _TRACE_COUNT
     _TRACE_COUNT += 1
     W = mesh.num_workers
@@ -1694,12 +1704,16 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         into this loop iteration (the famine-churn fast path).
 
         `_famine_horizon` certifies that every deque size is frozen over the
-        window, so the batched replay below needs no deque ops, no grant
+        window, so the replay below needs no deque ops, no grant
         resolution, and no recovery machinery — only the probe phase
-        machine, burn-downs, and stats. Victim draws are gathered from
-        `stealing.batched_victim_draws`, which replays the exact
-        ``fold_in(key0, t)``-keyed per-tick sequence, keeping the result
-        bit-identical to the one-tick oracle. Returns
+        machine, burn-downs, and stats. It is a `lax.while_loop` over the
+        window's ticks that stops at the window's end or when the run goes
+        idle, so it executes exactly the ticks it replays. Under the batched
+        entry points' vmap all points share one trip count, the longest
+        window, and a point past its own window steps through masked. Each
+        step draws its tick's victims with `stealing.victim_draws_code`, the
+        exact ``fold_in(key0, t)``-keyed per-tick sequence, keeping the
+        result bit-identical to the one-tick oracle. Returns
         (state, tr, t, live, ne, ran): `ne` is the `_next_event` horizon of
         the returned state, so the trailing leap never recomputes it, and
         `ran` says whether the replay ran.
@@ -1719,15 +1733,13 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
         def fast(state, tr, t, live):
             if ls is None:
                 eidx0, sp0 = None, speed
-                nbr_tab, tau_row = tbl["neighbors"], None
+                nbr_tab, adapt_tab = tbl["neighbors"], None
                 r2_tab, comp0 = tbl["radius2"], None
             else:
                 eidx0, sp0 = _epoch_view(ls, t)
                 nbr_tab, r2_tab, comp0 = _epoch_link_tables(tbl, ls, eidx0)
-                tau_row = ls.link_tau[eidx0]
-            near, far = stealing.batched_victim_draws_code(
-                code, key0, t, FB, nbr_tab, r2_tab,
-                num_workers=W, link_tau_row=tau_row)
+                adapt_tab = stealing.cheapest_live_table(
+                    nbr_tab, ls.link_tau[eidx0])
             empty0 = state.deque.size == 0
             alive0 = state.alive
             got0 = state.got
@@ -1736,21 +1748,40 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
                              + jnp.sum(got0.astype(jnp.int32)))
             # open-system liveness inside the replay: the window ends at or
             # before arr_t (a `_scheduled_horizons` clip), so the flag is
-            # frozen over the whole batch
+            # frozen over the whole window
             open_live = (state.arr_t < _NEVER) if ar is not None else None
             warr = jnp.arange(W)
+            t_end = t + delta
 
-            def step(carry, xs):
+            def active(carry):
+                # `pred` is True wherever `fast` is called alone; under vmap
+                # the cond runs `fast` for every lane, and a lane whose
+                # replay is discarded must not lengthen the loop
+                t_c, live_c = carry[11:13]
+                return (t_c < t_end) & live_c & pred
+
+            def in_window(carry):
+                act = active(carry)
+                if points_axis is None:
+                    return act
+                # one trip count for all lanes, the longest window: a
+                # per-lane predicate would make vmap select every carry,
+                # the recorder ring included, on every step
+                return jax.lax.psum(act.astype(jnp.int32), points_axis) > 0
+
+            def step(carry):
                 if trc is not None:
                     (phase, timer, victim, fails, work, loot, attempts, busy,
-                     steal_wait, hops_lo, hops_hi, t_c, live_c,
+                     steal_wait, hops_lo, hops_hi, tj, live_c,
                      ev, n, req_lane) = carry
                 else:
                     (phase, timer, victim, fails, work, loot, attempts, busy,
-                     steal_wait, hops_lo, hops_hi, t_c, live_c) = carry
-                j, near_j, far_j = xs
-                act = live_c & (j < delta)
-                tj = t + j
+                     steal_wait, hops_lo, hops_hi, tj, live_c) = carry
+                # False only for a lane past its own window under vmap
+                act = active(carry)
+                near_j, far_j = stealing.victim_draws_code(
+                    code, key0, tj, nbr_tab, r2_tab, num_workers=W,
+                    adaptive_table=adapt_tab)
                 # ---- phase RUN: burn / start a (failing) probe ---------- #
                 active_tick = alive0 & (tj % sp0 == 0)
                 running = (phase == PHASE_RUN) & active_tick
@@ -1852,21 +1883,20 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
                 sup_live = (jnp.sum(work) + frozen_supply) > 0
                 if ar is not None:
                     sup_live = sup_live | open_live
-                live_c = jnp.where(act, sup_live, live_c)
-                t_c = t_c + act.astype(jnp.int32)
                 out = (phase, timer, victim, fails, work, loot, attempts,
-                       busy, steal_wait, hops_lo, hops_hi, t_c, live_c)
+                       busy, steal_wait, hops_lo, hops_hi,
+                       tj + act.astype(jnp.int32),
+                       jnp.where(act, sup_live, live_c))
                 if trc is not None:
                     out = out + (ev, n, req_lane)
-                return out, None
+                return out
 
             carry0 = (state.phase, state.timer, state.victim, state.fails,
                       state.work, state.loot, state.attempts, state.busy,
                       state.steal_wait, state.hops_lo, state.hops_hi, t, live)
             if trc is not None:
                 carry0 = carry0 + (tr.ev, tr.n, tr.req_ticks)
-            xs = (jnp.arange(FB), near, far)
-            out, _ = jax.lax.scan(step, carry0, xs)
+            out = jax.lax.while_loop(in_window, step, carry0)
             (phase, timer, victim, fails, work, loot, attempts, busy,
              steal_wait, hops_lo, hops_hi, t_out, live_out) = out[:13]
             new_state = state._replace(
@@ -1955,6 +1985,9 @@ def _sim_core(workload, mesh: topo.MeshTopology, cfg: StaticConfig,
 
 _sim_jit = partial(jax.jit, static_argnames=("workload", "mesh", "cfg"))(_sim_core)
 
+# vmap axis name of the points of `simulate_batch` and `simulate_sweep`
+_POINTS = "points"
+
 
 @partial(jax.jit, static_argnames=("workload", "mesh", "cfg"))
 def _sim_batch_jit(workload, mesh, cfg, params, fail_time, wake_time,
@@ -1967,8 +2000,8 @@ def _sim_batch_jit(workload, mesh, cfg, params, fail_time, wake_time,
     across the batch, closed over un-vmapped."""
     return jax.vmap(
         lambda p, ft, wt, fp, sp: _sim_core(workload, mesh, cfg, p, ft, wt,
-                                            fp, sp, ls, ar)
-    )(params, fail_time, wake_time, fail_period, speed)
+                                            fp, sp, ls, ar, _POINTS),
+        axis_name=_POINTS)(params, fail_time, wake_time, fail_period, speed)
 
 
 # (workload, mesh, StaticConfig, devices) -> jitted shard_map'd sweep fn.
@@ -1989,8 +2022,8 @@ def _sharded_sweep_fn(workload, mesh, cfg: StaticConfig, devs):
             # per-device slice of the grid; vmap the points inside the shard
             return jax.vmap(
                 lambda p, a, b, c, d: _sim_core(workload, mesh, cfg, p, a,
-                                                b, c, d, ls, ar)
-            )(params, ft, wt, fp, sp)
+                                                b, c, d, ls, ar, _POINTS),
+                axis_name=_POINTS)(params, ft, wt, fp, sp)
 
         fn = jax.jit(jax.shard_map(
             shard_body, mesh=dmesh,

@@ -204,9 +204,10 @@ def cheapest_live_table(neighbor_table: jax.Array,
                         link_tau: jax.Array) -> jax.Array:
     """Mask `neighbor_table` down to the τ-argmin set of each worker's live
     neighbors (NO_NEIGHBOR elsewhere). Single source of truth for the
-    link-aware ADAPTIVE near pick — shared by `choose_adaptive_linkaware`
-    and `batched_victim_draws` so the famine fast path's replay can never
-    drift from the per-tick preference rule."""
+    link-aware ADAPTIVE near pick — shared by `choose_adaptive_linkaware`,
+    `batched_victim_draws` and the famine replay's `victim_draws_code`
+    table, so the replay can never drift from the per-tick preference
+    rule."""
     valid = neighbor_table != topo.NO_NEIGHBOR
     cost = jnp.where(valid, link_tau, jnp.iinfo(jnp.int32).max)
     cheapest = valid & (cost == jnp.min(cost, axis=1, keepdims=True))
@@ -447,45 +448,40 @@ def probe_may_succeed_code(code, nonempty: jax.Array, fails: jax.Array,
                                          jnp.ones((W,), bool))))
 
 
-def batched_victim_draws_code(code, key0: jax.Array, t0, count: int,
-                              neighbor_table: jax.Array,
-                              radius2_table: jax.Array, *,
-                              num_workers: int,
-                              link_tau_row: jax.Array | None = None):
-    """Traced-strategy `batched_victim_draws`: dispatches over an int32
-    strategy code with `lax.switch` and always returns ``(near, far)`` of
-    shape (count, W) — `far` duplicates `near` for the single-draw
-    strategies, so the caller's escalation select reduces to the near draw.
-    Each branch uses the key exactly as its per-tick `_select` counterpart
-    (same splits, same `fold_in(key0, t)` schedule), preserving the
-    bit-identity of the famine replay. The LIFELINE branch returns global
+def victim_draws_code(code, key0: jax.Array, t, neighbor_table: jax.Array,
+                      radius2_table: jax.Array, *, num_workers: int,
+                      adaptive_table: jax.Array | None = None):
+    """One tick's victim draws for an all-thieves mask, dispatched over an
+    int32 strategy code with `lax.switch`: ``(near, far)``, each (W,), for
+    tick `t`. `far` duplicates `near` for the single-draw strategies, so the
+    caller's escalation select reduces to the near draw. Each branch uses
+    the key exactly as its per-tick `_select` counterpart (same splits, same
+    ``fold_in(key0, t)`` schedule), so the famine replay, which draws one
+    tick at a time with this function, stays bit-identical to the one-tick
+    oracle. `adaptive_table` is ADAPTIVE's near set, `neighbor_table` when
+    omitted; under a link-state schedule the caller passes
+    `cheapest_live_table(neighbor_table, link_tau_row)`, built once per
+    epoch rather than once per draw. The LIFELINE branch returns global
     draws as a placeholder: the simulator's famine path is predicate-gated
     off for LIFELINE, so the branch output can only be produced — and then
     discarded — under vmapped-switch execute-all-branches semantics."""
     W = num_workers
     all_thieves = jnp.ones((W,), bool)
-    ticks = t0 + jnp.arange(count)
-    keys = jax.vmap(lambda t: jax.random.fold_in(key0, t))(ticks)
+    key = jax.random.fold_in(key0, t)
+    near_tab = neighbor_table if adaptive_table is None else adaptive_table
 
     def b_global(_):
-        near = jax.vmap(lambda k: choose_global(k, W, all_thieves))(keys)
+        near = choose_global(key, W, all_thieves)
         return near, near
 
     def b_neighbor(_):
-        near = jax.vmap(
-            lambda k: choose_neighbor(k, neighbor_table, all_thieves))(keys)
+        near = choose_neighbor(key, neighbor_table, all_thieves)
         return near, near
 
     def b_adaptive(_):
-        near_tab = (neighbor_table if link_tau_row is None
-                    else cheapest_live_table(neighbor_table, link_tau_row))
-
-        def draw(k):
-            k1, k2 = jax.random.split(k)
-            return (_pick_from_list(k1, near_tab, all_thieves),
-                    _pick_from_list(k2, radius2_table, all_thieves))
-
-        return jax.vmap(draw)(keys)
+        k1, k2 = jax.random.split(key)
+        return (_pick_from_list(k1, near_tab, all_thieves),
+                _pick_from_list(k2, radius2_table, all_thieves))
 
     # dispatch-table order == the strategy code order
     return jax.lax.switch(code, [b_global, b_neighbor, b_global, b_adaptive],
@@ -496,7 +492,8 @@ def batched_victim_draws(strategy: Strategy, key0: jax.Array, t0, count: int,
                          neighbor_table: jax.Array,
                          radius2_table: jax.Array | None, *,
                          num_workers: int, link_tau_row: jax.Array | None = None):
-    """Replay `count` consecutive ticks' victim draws in one fused batch.
+    """Replay `count` consecutive ticks' victim draws in one batch, per
+    strategy enum — the oracle the tests hold `victim_draws_code` to.
 
     Returns ``(near, far)`` of shape (count, W): row j holds the victims
     the per-tick selection would draw at tick ``t0 + j`` for an

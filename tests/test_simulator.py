@@ -836,7 +836,7 @@ def test_famine_batch_size_never_changes_results(tau):
     (including 0 = disabled) only trades iterations for per-iteration work
     — every setting reproduces the identical SimResult."""
     ref = None
-    for fb in (0, 1, 7, 64):
+    for fb in (0, 1, 7, 64, 256):
         cfg = simulator.SimConfig(strategy=stealing.Strategy.NEIGHBOR,
                                   hop_ticks=tau, capacity=64,
                                   max_ticks=100_000, famine_batch=fb)
@@ -855,11 +855,11 @@ def test_per_worker_overflow_sums_and_famine_batch_invariant_linkstate():
     """Property (extends the PR 3 sweep to the linkstate path): under a
     dynamic link-state schedule with an outage epoch and a capacity small
     enough to actually drop tasks, `per_worker_overflow` always sums to the
-    scalar overflow, and famine_batch ∈ {0, 1, 7, 64} reproduces the
+    scalar overflow, and famine_batch ∈ {0, 1, 7, 64, 256} reproduces the
     identical result — per-worker breakdown included."""
     ls, ft = _dynamic_schedule()
     ref = None
-    for fb in (0, 1, 7, 64):
+    for fb in (0, 1, 7, 64, 256):
         cfg = simulator.SimConfig(strategy=stealing.Strategy.NEIGHBOR,
                                   capacity=2, max_ticks=200_000,
                                   preshed=True, warn_ticks=8,
@@ -1200,19 +1200,21 @@ def _count_case(strategy, mode, dynamic):
     return cfg, dict(linkstate=ls, fail_time=ft, arrivals=COUNT_ARRIVALS)
 
 
-def _loop_args(cfg, linkstate=None, fail_time=None, arrivals=None):
+def _loop_args(cfg, linkstate=None, fail_time=None, arrivals=None,
+               mesh=EQ_MESH):
     """The compiled loop's arguments, as `simulate` prepares them."""
-    ft, wt, fp, sp = simulator._fail_speed_arrays(EQ_MESH.num_workers,
+    ft, wt, fp, sp = simulator._fail_speed_arrays(mesh.num_workers,
                                                   fail_time, None)
     return (ft, wt, fp, sp,
-            simulator._linkstate_tables(linkstate, EQ_MESH, None),
-            simulator._arrival_tables(arrivals, EQ_MESH))
+            simulator._linkstate_tables(linkstate, mesh, None),
+            simulator._arrival_tables(arrivals, mesh))
 
 
-def _step_counts(cfg, **kw):
+def _step_counts(cfg, wl=EQ_FIB, mesh=EQ_MESH, **kw):
     scfg, params = cfg.split()
-    _, _, ticks, counts = simulator._sim_jit(EQ_FIB, EQ_MESH, scfg, params,
-                                             *_loop_args(cfg, **kw))
+    _, _, ticks, counts = simulator._sim_jit(wl, mesh, scfg, params,
+                                             *_loop_args(cfg, mesh=mesh,
+                                                         **kw))
     return int(ticks), {k: int(v) for k, v in counts._asdict().items()}
 
 
@@ -1257,6 +1259,55 @@ def test_sweep_step_counts_equal_per_point_counts():
         want_ticks, want = _step_counts(one, **kw)
         assert int(ticks[i]) == want_ticks
         assert {k: int(v[i]) for k, v in counts._asdict().items()} == want
+
+
+def test_sweep_famine_windows_differ_per_point():
+    """Points whose famine windows differ — NEIGHBOR and GLOBAL at τ 1 and
+    5, and LIFELINE, whose replay predicate is always false — share one
+    vmapped replay loop, which runs to the longest lane's window: each
+    point still gets exactly its own `simulate`'s `SimResult`, recorder
+    ring and time series included, and its own `StepCounts`."""
+    import jax
+
+    mesh = topology.MeshTopology.grid(4, 5, torus=True)
+    cfg = simulator.SimConfig(
+        capacity=64, max_ticks=100_000,
+        trace=tracing.TraceConfig(ring_capacity=4096, bins=16,
+                                  bin_ticks=64))
+    cases = [(stealing.Strategy.NEIGHBOR, 1), (stealing.Strategy.NEIGHBOR, 5),
+             (stealing.Strategy.GLOBAL, 1), (stealing.Strategy.GLOBAL, 5),
+             (stealing.Strategy.LIFELINE, 5)]
+    cfgs = [dataclasses.replace(cfg, strategy=s, hop_ticks=tau, seed=k)
+            for k, (s, tau) in enumerate(cases)]
+    pts = [c.params for c in cfgs]
+    B = len(pts)
+    swept = simulator.simulate_sweep(FAMINE_WL, mesh, cfg, pts)
+    ft, wt, fp, sp, ls, ar = _loop_args(cfg, mesh=mesh)
+    _, _, _, counts = jax.device_get(simulator._sim_batch_jit(
+        FAMINE_WL, mesh, cfg.static, simulator.stack_params(pts),
+        *(jnp.broadcast_to(x[None], (B,) + x.shape) for x in (ft, wt, fp,
+                                                             sp)), ls, ar))
+    per_point = []
+    for i, one in enumerate(cfgs):
+        a, b = swept[i], simulator.simulate(FAMINE_WL, mesh, one)
+        for f in EQ_FIELDS + ("events",):
+            assert getattr(a, f) == getattr(b, f), (cases[i], f)
+        for f in ("per_worker_busy", "per_worker_overflow",
+                  "per_worker_stolen", "per_worker_attempts",
+                  "per_worker_successes", "per_worker_hiwater"):
+            assert (getattr(a, f) == getattr(b, f)).all(), (cases[i], f)
+        assert a.trace.emitted == b.trace.emitted > 0, cases[i]
+        assert (a.trace.events == b.trace.events).all(), cases[i]
+        assert (a.timeseries.data == b.timeseries.data).all(), cases[i]
+        assert a.result == FAMINE_WL.expected_result(), cases[i]
+        want_ticks, want = _step_counts(one, wl=FAMINE_WL, mesh=mesh)
+        got = {k: int(v[i]) for k, v in counts._asdict().items()}
+        assert (a.ticks, got) == (want_ticks, want), cases[i]
+        per_point.append((got["famine_replays"], got["famine_ticks"]))
+    # the lanes really replay different windows, and LIFELINE none
+    assert per_point[-1] == (0, 0)
+    assert all(r > 0 for r, _ in per_point[:-1]), per_point
+    assert len(set(per_point)) > 2, per_point
 
 
 def test_simulate_span_carries_step_counts(tmp_path):

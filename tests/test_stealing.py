@@ -290,6 +290,46 @@ def test_batched_victim_draws_linkaware_adaptive():
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("strategy,linkaware", [
+    (stealing.Strategy.GLOBAL, False),
+    (stealing.Strategy.NEIGHBOR, False),
+    (stealing.Strategy.LIFELINE, False),
+    (stealing.Strategy.ADAPTIVE, False),
+    (stealing.Strategy.ADAPTIVE, True),
+], ids=["global", "neighbor", "lifeline", "adaptive", "adaptive-linkaware"])
+def test_victim_draws_code_equals_batched_rows(strategy, linkaware):
+    """The famine replay's per-tick draw, `victim_draws_code` called at
+    tick t0 + j under the traced strategy code, equals row j of the enum
+    `batched_victim_draws` oracle, near and far, for every code. With a
+    link_tau row, ADAPTIVE's near set is the caller-built
+    `cheapest_live_table`. LIFELINE's branch is the GLOBAL placeholder
+    (the replay is gated off for it)."""
+    import jax
+    mesh = topology.MeshTopology.grid(4, 5, torus=True)
+    W = mesh.num_workers
+    nbrs = jnp.asarray(stealing.neighbor_list(mesh))
+    r2 = jnp.asarray(stealing.radius2_list(mesh))
+    tau = (jnp.asarray((np.arange(W * 4) * 7 % 5 + 1).reshape(W, 4),
+                       jnp.int32) if linkaware else None)
+    key0 = jax.random.PRNGKey(2**31 + 5)
+    t0, count = 1_000_003, 7
+    oracle = (stealing.Strategy.GLOBAL
+              if strategy is stealing.Strategy.LIFELINE else strategy)
+    near, far = stealing.batched_victim_draws(
+        oracle, key0, t0, count, nbrs, r2, num_workers=W, link_tau_row=tau)
+    if far is None:
+        far = near
+    adapt = None if tau is None else stealing.cheapest_live_table(nbrs, tau)
+    draw = jax.jit(lambda code, t: stealing.victim_draws_code(
+        code, key0, t, nbrs, r2, num_workers=W, adaptive_table=adapt))
+    code = jnp.int32(stealing.strategy_code(strategy))
+    for j in range(count):
+        got_near, got_far = draw(code, jnp.int32(t0 + j))
+        np.testing.assert_array_equal(np.asarray(got_near),
+                                      np.asarray(near[j]))
+        np.testing.assert_array_equal(np.asarray(got_far), np.asarray(far[j]))
+
+
 # --------------------------------------------------------------------------- #
 # attach_hops: coords-based pricing ≡ dense hop_matrix oracle
 # --------------------------------------------------------------------------- #
