@@ -10,11 +10,26 @@ takes anything the other made. Inputs that the deployment fixes, such as the ecl
 schedule, are generated here from the configuration's own seed; what
 varies between answers is the answer's seed alone.
 
+Configuration key `mesh` (the others are sizes named in the file):
+
+  mesh            {"kind": "grid", "rows", "cols", "torus"}:
+                  `MeshTopology.grid`; or {"kind": "square", "workers",
+                  "torus"}: `MeshTopology.square`, ceil(sqrt(workers)) wide,
+                  rows filled in order, the last one ragged
+
 Traffic keys (all but `answer` and `job` optional):
 
-  answer          "simulate": one `simulator.simulate` call per answer
-  job             {"type": "fib", "n", "cutoff", "max_leaf_cost"}
-  strategy        victim-selection strategy, by name
+  answer          "simulate": one `simulator.simulate` call per answer;
+                  "sweep": one `simulator.simulate_sweep` call per answer
+                  over the points of `grid`, on the cell's own chips; the
+                  reference makes the same points one `simulate` call
+                  each. Every point of one answer takes that answer's seed.
+  grid            (sweep only) {"strategy": [names], "hop_ticks": [ticks]}:
+                  the points are their Cartesian product, strategy-major
+                  (every τ of the first strategy, then of the next)
+  job             {"type": "fib", "n", "cutoff", "max_leaf_cost"} or
+                  {"type": "uts", "b0", "d_max", "root_seed"}
+  strategy        (simulate only) victim-selection strategy, by name
   seed_pool       the window cycles through this many answers, starting at
                   the run's seed (default: each answer takes a new seed)
   orbits          one answer runs this many orbits of the deployment's
@@ -110,6 +125,9 @@ def make_mesh(m, spec: dict):
         return m.topology.MeshTopology.grid(int(spec["rows"]),
                                             int(spec["cols"]),
                                             torus=bool(spec["torus"]))
+    if spec["kind"] == "square":
+        return m.topology.MeshTopology.square(int(spec["workers"]),
+                                              torus=bool(spec["torus"]))
     raise ValueError(f"unknown mesh kind {spec['kind']!r}")
 
 
@@ -117,6 +135,10 @@ def make_job(m, job: dict):
     if job["type"] == "fib":
         return m.tasks.FibWorkload(n=int(job["n"]), cutoff=int(job["cutoff"]),
                                    max_leaf_cost=int(job["max_leaf_cost"]))
+    if job["type"] == "uts":
+        return m.tasks.UtsWorkload(b0=float(job["b0"]),
+                                   d_max=int(job["d_max"]),
+                                   root_seed=int(job["root_seed"]))
     raise ValueError(f"unknown job type {job['type']!r}")
 
 
@@ -179,6 +201,12 @@ def extra_seed(seed: int, pool: int | None = None) -> int:
     return p + int(seed) % (SEED_MOD - p)
 
 
+def grid_points(m, grid: dict) -> list[tuple]:
+    """A sweep's points, `(Strategy, hop_ticks)`, strategy-major."""
+    return [(m.stealing.Strategy(s), int(t))
+            for s in grid["strategy"] for t in grid["hop_ticks"]]
+
+
 @dataclasses.dataclass
 class Plan:
     """One cell's answer, ready to run on one side (program or reference)."""
@@ -189,15 +217,37 @@ class Plan:
     cfg: object              # SimConfig of one answer (seed set per call)
     kwargs: dict             # schedules and prebuilt tables
     timings: dict            # host seconds of the table builds
+    points: list | None = None    # a sweep's (strategy, hop_ticks) points
+    devices: list | None = None   # the chips a sweep runs on
 
     def seed(self, seed: int, index: int) -> int:
         """The seed of the window's answer `index` (see `answer_seed`)."""
         return answer_seed(seed, index, self.cell.traffic.get("seed_pool"))
 
+    def point_configs(self, seed: int) -> list:
+        """A sweep answer's points as SimConfigs, each with the answer's
+        seed."""
+        return [dataclasses.replace(self.cfg, strategy=s, hop_ticks=t,
+                                    seed=int(seed))
+                for s, t in self.points]
+
+    def sweep_devices(self) -> list:
+        """The chips a sweep runs on: the harness's, else the first
+        `chips` local devices, never every device of the host."""
+        if self.devices is not None:
+            return list(self.devices)
+        import jax
+        return jax.local_devices()[:self.cell.chips]
+
     def run(self, seed: int, kwargs: dict | None = None):
         """One answer: the program's (or reference's) `simulate`, with the
-        results fetched."""
+        results fetched; for a sweep, `simulate_sweep` and its list of
+        results, one per point."""
         kw = self.kwargs if kwargs is None else kwargs
+        if self.points is not None:
+            return self.m.simulator.simulate_sweep(
+                self.job, self.mesh, self.cfg, self.point_configs(seed),
+                devices=self.sweep_devices(), **kw)
         cfg = dataclasses.replace(self.cfg, seed=int(seed))
         return self.m.simulator.simulate(self.job, self.mesh, cfg, **kw)
 
@@ -213,19 +263,25 @@ class Plan:
         return kw
 
 
-def build(cell: Cell, m: SimpleNamespace) -> Plan:
+def build(cell: Cell, m: SimpleNamespace, devices=None) -> Plan:
     """Build one side's answer of `cell` with the modules `m`, each at its
-    own defaults: the program's are the platform's backends and stepper."""
+    own defaults: the program's are the platform's backends and stepper.
+    A sweep runs on `devices` (see `Plan.sweep_devices`)."""
     conf, tr = cell.config, cell.traffic
-    if tr["answer"] != "simulate":
+    if tr["answer"] == "simulate":
+        points = None
+        strategy, hop = m.stealing.Strategy(tr["strategy"]), conf["hop_ticks"]
+    elif tr["answer"] == "sweep":
+        points = grid_points(m, tr["grid"])
+        strategy, hop = points[0]
+    else:
         raise ValueError(f"unknown answer kind {tr['answer']!r}")
     mesh = make_mesh(m, conf["mesh"])
     W = mesh.num_workers
     job = make_job(m, tr["job"])
     timings: dict = {}
     kwargs: dict = {}
-    cfg_kw = dict(strategy=m.stealing.Strategy(tr["strategy"]),
-                  hop_ticks=int(conf["hop_ticks"]),
+    cfg_kw = dict(strategy=strategy, hop_ticks=int(hop),
                   capacity=int(conf["capacity"]),
                   max_ticks=horizon_ticks(cell))
     if "orbits" in tr:
@@ -270,14 +326,20 @@ def build(cell: Cell, m: SimpleNamespace) -> Plan:
             bin_ticks=-(-h // bins))
     cfg = m.simulator.SimConfig(**cfg_kw)
     return Plan(m=m, cell=cell, mesh=mesh, job=job, cfg=cfg, kwargs=kwargs,
-                timings=timings)
+                timings=timings, points=points,
+                devices=None if devices is None else list(devices))
 
 
-def expected(cell: Cell) -> dict | None:
+def expected(cell: Cell) -> dict | list | None:
     """Closed form of every drained answer, from the reference's task
-    tree: FIB result and node count."""
+    tree: result checksum and node count; for a sweep, a list with one
+    per point (every point drains the same tree)."""
     if not drains(cell):
         return None
-    job = make_job(reference_modules(), cell.traffic["job"])
-    return {"result": int(job.expected_result()),
-            "nodes": int(job.expected_nodes())}
+    rm = reference_modules()
+    job = make_job(rm, cell.traffic["job"])
+    closed = {"result": int(job.expected_result()),
+              "nodes": int(job.expected_nodes())}
+    if cell.traffic["answer"] == "sweep":
+        return [closed] * len(grid_points(rm, cell.traffic["grid"]))
+    return closed

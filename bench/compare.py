@@ -6,7 +6,9 @@ reference's value for value: scalars, per-worker arrays, and, where the
 flight recorder is on, the event ring, the time series and the sojourn
 percentiles. The one field left out is `events`, the stepper's loop count,
 which says how the answer was stepped and not what it simulated. The
-compared number is how many values differ; its limit is 0.
+compared number is how many values differ; its limit is 0. A sweep's
+answer is a list of SimResults, one per grid point, compared point by
+point.
 
 Beside the reference, checks that go through no simulator at all: the
 closed form of a drained job, and, for open-loop requests, the ledger of
@@ -56,7 +58,14 @@ def _count(a, b) -> int:
 
 
 def differing_values(got, want) -> int:
-    """Values that differ between two SimResults, `events` left out."""
+    """Values that differ between two SimResults, `events` left out; for
+    two sweeps' lists of SimResults, the sum over their points, each point
+    that one list has and the other lacks counting as one."""
+    if isinstance(got, list) or isinstance(want, list):
+        if not (isinstance(got, list) and isinstance(want, list)):
+            return 1
+        return (sum(differing_values(g, w) for g, w in zip(got, want))
+                + abs(len(got) - len(want)))
     return _count(got._replace(**{k: 0 for k in NOT_COMPARED}),
                   want._replace(**{k: 0 for k in NOT_COMPARED}))
 

@@ -22,6 +22,11 @@ answer and one window answer, drawn from the seed, against the reference,
 which steps the same model one tick at a time. It prints its metrics: the
 cell's end-to-end metrics with `--trace 0`, its per-layer metrics with
 `--trace 1`.
+
+A sweep's answer is one `simulate_sweep` call over the mix's grid, on the
+cell's own chips: its ticks are the sum over its points, its loop
+iterations the largest point's, its checks run on every point, and the
+reference makes each point with its own `simulate` call.
 """
 
 from __future__ import annotations
@@ -98,13 +103,24 @@ def check_devices(chips: int):
 @dataclasses.dataclass
 class Answer:
     """One answer: its seed, its host-clock span, its SimResult and that
-    result's ticks and loop iterations."""
+    result's ticks and loop iterations. A sweep's answer keeps its list of
+    SimResults, one per point; its ticks are the sum over the points and
+    its events the largest count, which is the iterations of the loop that
+    the points step together."""
     seed: int
     t0: float
     t1: float
     result: object
     ticks: int
     events: int
+
+
+def answer_counts(result) -> tuple[int, int]:
+    """`(ticks, events)` of one answer's result (see `Answer`)."""
+    if isinstance(result, list):
+        return (sum(int(r.ticks) for r in result),
+                max((int(r.events) for r in result), default=0))
+    return int(result.ticks), int(result.events)
 
 
 class Profile:
@@ -159,10 +175,16 @@ def cell_metrics(bench: dict, cell_name: str, traced: bool) -> list[dict]:
 
 
 def kernel_shapes(plan) -> dict:
+    """The shapes of one kernel call. `points` is how many grid points one
+    call serves: 1 for `simulate`; for a sweep, which vmaps its points
+    into every call, the points on each of its chips."""
     from bench.reference import deque, stealing, tasks
+    points = 1
+    if plan.points is not None:
+        points = -(-len(plan.points) // len(plan.sweep_devices()))
     return {"workers": plan.mesh.num_workers,
             "width": deque.TASK_WIDTH, "grant_width": stealing.GRANT_WIDTH,
-            "lanes": tasks.EXPAND_K + 1}
+            "lanes": tasks.EXPAND_K + 1, "points": points}
 
 
 def timed_answer(plan, seed: int, call) -> Answer:
@@ -170,26 +192,45 @@ def timed_answer(plan, seed: int, call) -> Answer:
     with span("bench.answer"):
         res = call(plan, seed)
     with span("bench.fetch"):
-        ans = Answer(seed, a0, 0.0, res, int(res.ticks), int(res.events))
+        ans = Answer(seed, a0, 0.0, res, *answer_counts(res))
     ans.t1 = time.perf_counter()
     return ans
 
 
 def answer_checks(cell: cells.Cell, ans: Answer, expect, ref_plan,
                   offered: dict) -> dict:
-    """The checks of one answer that need no simulator. `offered` caches
-    the stream's offered requests by seed."""
-    r = ans.result
+    """The checks of one answer that need no simulator, each summed over a
+    sweep's points, with `points_missing` for a sweep that returned
+    another number of points than its grid has. `offered` caches the
+    stream's offered requests by seed."""
+    if not isinstance(ans.result, list):
+        return point_checks(cell, ans.seed, ans.result, expect, ref_plan,
+                            offered)
+    want = len(ref_plan.points)
+    expect = expect or [None] * want
+    per_point = [point_checks(cell, ans.seed, r, e, ref_plan, offered)
+                 for r, e in zip(ans.result, expect)]
+    out: dict = {}
+    for c in per_point:
+        for k, v in c.items():
+            out[k] = out.get(k, 0) + v
+    out["points_missing"] = abs(len(ans.result) - want)
+    return out
+
+
+def point_checks(cell: cells.Cell, seed: int, r, expect, ref_plan,
+                 offered: dict) -> dict:
+    """The checks that need no simulator, on one SimResult."""
     out = {"closed_form_misses": compare.closed_form_misses(r, expect),
            "undrained": int(bool(expect) and r.ticks >= cells.DRAIN_MAX_TICKS),
            "overflow_tasks": int(r.overflow),
            "trace_dropped": compare.trace_dropped(r)}
     if cell.traffic.get("arrivals"):
         cfg = ref_plan.cfg
-        key = (ans.seed, int(r.ticks))
+        key = (seed, int(r.ticks))
         if key not in offered:
             offered[key] = compare.offered_requests(
-                ref_plan.kwargs["arrivals"], ans.seed, cfg.arrival_gap_q8,
+                ref_plan.kwargs["arrivals"], seed, cfg.arrival_gap_q8,
                 cfg.arrival_batch, int(r.ticks))
         out.update(compare.request_ledger(
             r, offered[key], int(ref_plan.kwargs["arrivals"].task_cost)))
@@ -208,7 +249,7 @@ def run_cell(cell: cells.Cell, args, devices, *, clock0: float,
     if args.control:
         jax.config.update("jax_threefry_partitionable", False)
     with span("bench.setup"):
-        plan = cells.build(cell, cells.program_modules())
+        plan = cells.build(cell, cells.program_modules(), devices=used)
         call = entry or (lambda p, s: p.run(s))
         t = time.perf_counter()
         plan.run(plan.seed(args.seed, 0), plan.dead_kwargs())
@@ -258,12 +299,15 @@ def run_cell(cell: cells.Cell, args, devices, *, clock0: float,
         per_answer = [answer_checks(cell, a, expect, ref_plan, offered)
                       for a in checked]
         pick = random.Random(args.seed).randrange(len(answers))
+        t = time.perf_counter()
         for i in (pick, len(checked) - 1):
             per_answer[i]["ref_diff_values"] = compare.differing_values(
                 checked[i].result, ref_plan.run(checked[i].seed))
+        ref_s = time.perf_counter() - t
     print(f"bench: window {window_s:.3f} s, extra answer "
           f"{extra.t1 - extra.t0:.3f} s, after it "
-          f"{time.perf_counter() - extra.t1:.3f} s", file=sys.stderr)
+          f"{time.perf_counter() - extra.t1:.3f} s, of which the reference's "
+          f"two answers {ref_s:.3f} s", file=sys.stderr)
     checks = {k: sum(c.get(k, 0) for c in per_answer)
               for k in per_answer[-1]}
     failed = sum(any(c.values()) for c in per_answer)
@@ -273,7 +317,8 @@ def run_cell(cell: cells.Cell, args, devices, *, clock0: float,
     ctx = SimpleNamespace(
         cell=cell, setup_s=setup_s, compile_s=compile_s, tables_s=tables_s,
         answers=answers, window_s=window_s, peak_bytes=peak,
-        trace=trace, shapes=shapes, device_kind=used[0].device_kind)
+        trace=trace, shapes=shapes, device_kind=used[0].device_kind,
+        devices=used)
     metrics = {}
     for spec in cell_metrics(bench, cell.name, bool(args.trace)):
         value = metric_reader(spec["name"], root)(ctx)

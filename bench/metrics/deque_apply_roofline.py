@@ -1,6 +1,7 @@
 """deque_apply_roofline: per cent of the HBM-bandwidth bound that the
 `deque_apply` kernel reached in the profiled window: the bytes its calls
-need (`bench.peaks.deque_apply_bytes`) over peak bandwidth, divided by the
+need at the call's shapes and grid points
+(`bench.peaks.deque_apply_bytes`) over peak bandwidth, divided by the
 device time of its events. An integer kernel: bounded by bandwidth alone.
 Absent when the trace holds no call of it."""
 
@@ -13,5 +14,5 @@ def read(run):
         return None
     s = run.shapes
     need = k["count"] * peaks.deque_apply_bytes(
-        s["workers"], s["lanes"], s["width"])
+        s["workers"], s["lanes"], s["width"], s["points"])
     return peaks.roofline_share(need, k["device_s"], run.device_kind)

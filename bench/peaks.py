@@ -33,21 +33,25 @@ def peak(device_kind: str) -> dict:
 # kernel for wasted traffic and let a better kernel, which moves less, read
 # above 100%. Both kernels are integer kernels, and the peak table holds no
 # integer-op peak that applies to them, so their roofline share is bounded
-# by HBM bandwidth alone.
+# by HBM bandwidth alone. Under a sweep's `vmap` one call serves every grid
+# point on its chip, so a call needs `points` times one point's bytes.
 
-def steal_compact_bytes(workers: int, grant_width: int, width: int) -> int:
+def steal_compact_bytes(workers: int, grant_width: int, width: int,
+                        points: int = 1) -> int:
     """One `steal_compact` call: the `grant_width` x `width` int32 records
     it exports per worker, plus the per-worker cursors it reads (bottom,
-    size, grants) and writes (bottom, size)."""
-    return INT32_BYTES * workers * (grant_width * width + 5)
+    size, grants) and writes (bottom, size), for each of `points`."""
+    return INT32_BYTES * points * workers * (grant_width * width + 5)
 
 
-def deque_apply_bytes(workers: int, lanes: int, width: int) -> int:
+def deque_apply_bytes(workers: int, lanes: int, width: int,
+                      points: int = 1) -> int:
     """One `deque_apply` call: the `lanes` x `width` int32 records pushed per
-    worker, plus their slot vector and the per-worker lane count. `lanes` is
-    the push log a tick needs on the common path (expansion children and
-    the thief's loot), not the lanes reserved for rare transplants."""
-    return INT32_BYTES * workers * (lanes * width + lanes + 1)
+    worker, plus their slot vector and the per-worker lane count, for each
+    of `points`. `lanes` is the push log a tick needs on the common path
+    (expansion children and the thief's loot), not the lanes reserved for
+    rare transplants."""
+    return INT32_BYTES * points * workers * (lanes * width + lanes + 1)
 
 
 def roofline_share(needed_bytes: float, device_s: float,

@@ -4,8 +4,9 @@ Device scopes. The simulator wraps each layer of its device loop in a
 `jax.named_scope` (`SCOPES`). A scope name lands in the `op_name` metadata
 of every op it encloses. A TPU op event carries no such stat, so each op
 name's path comes from the loop's compiled HLO text
-(`simulator.lower_simulate`, compiled again from the compile cache, so with
-the op names the device ran), read once per distinct op name. The plain
+(`simulator.lower_simulate`, or `simulator.lower_simulate_sweep` for a
+sweep, compiled again from the compile cache, so with the op names the
+device ran), read once per distinct op name. The plain
 form that a test can hold as JSON is
 
     {"devices": {plane: [[op, dur_ns], ...]}, "paths": {op: path}}
@@ -21,7 +22,9 @@ answer, not the whole of it.
 Host spans and step counters. `simulate` writes its host spans
 (`simulate` over `simulate.prepare`, `.dispatch`, `.wait`, `.fetch`,
 `.finalize`) on the profiler's clock, and the root span's arguments carry
-the loop's exact step counters (`COUNTERS`). The window's answers outlast
+the loop's exact step counters (`COUNTERS`). `simulate_sweep` writes the
+same children under a root span of its own name, whose counters are
+summed over the grid's points. The window's answers outlast
 the device window, so `profile_answer` makes the window's first answer once
 more under a profiler of its own, host only (`tpu_trace_mode`
 `TRACE_ONLY_HOST`), in `bench/out/trace_host`, and `host_summary` reduces
@@ -47,7 +50,7 @@ SCOPES = ("stepper.tick", "tick.linkstate", "tick.preshed", "tick.arrivals",
           "tick.grant", "tick.commit", "tick.recorder", "stepper.leap",
           "stepper.famine")
 UNSCOPED = "unscoped"
-ROOT_SPAN = "simulate"
+ROOT_SPANS = ("simulate", "simulate_sweep")
 CHILD_SPANS = ("simulate.prepare", "simulate.dispatch", "simulate.wait",
                "simulate.fetch", "simulate.finalize")
 COUNTERS = ("ticks", "iterations", "leap_ticks", "famine_ticks",
@@ -127,13 +130,22 @@ def hlo_op_paths(text: str) -> dict:
 def program_op_paths(plan) -> dict | None:
     """`{op: op_name}` of the loop that `plan`'s answers run, compiled once
     more: the compile cache holds it, so its op names are the ones the
-    device ran. None for a program without `simulator.lower_simulate`,
-    which names no scopes."""
-    lower = getattr(plan.m.simulator, "lower_simulate", None)
-    if lower is None:
-        return None
-    return hlo_op_paths(lower(plan.job, plan.mesh, plan.cfg, **plan.kwargs)
-                        .compile().as_text())
+    device ran. A sweep's loop is lowered by
+    `simulator.lower_simulate_sweep`, called as `simulate_sweep` is. None
+    for a program without the lowering function, which names no scopes."""
+    if plan.points is not None:
+        lower = getattr(plan.m.simulator, "lower_simulate_sweep", None)
+        if lower is None:
+            return None
+        lowered = lower(plan.job, plan.mesh, plan.cfg,
+                        plan.point_configs(0),
+                        devices=plan.sweep_devices(), **plan.kwargs)
+    else:
+        lower = getattr(plan.m.simulator, "lower_simulate", None)
+        if lower is None:
+            return None
+        lowered = lower(plan.job, plan.mesh, plan.cfg, **plan.kwargs)
+    return hlo_op_paths(lowered.compile().as_text())
 
 
 def device_ops(path: Path) -> dict:
@@ -192,16 +204,17 @@ def load_spans(path: Path) -> tuple[list, int]:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name == ROOT_SPAN or ev.name in CHILD_SPANS:
+                if ev.name in ROOT_SPANS or ev.name in CHILD_SPANS:
                     spans.append([ev.name, int(ev.start_ns),
                                   int(ev.duration_ns), dict(ev.stats)])
     return sorted(spans, key=lambda s: s[1]), device_events
 
 
 def host_summary(spans: list) -> dict | None:
-    """The last `simulate` span: its seconds, its children's seconds, and
-    its counters; None when the trace holds none that carries them."""
-    roots = [s for s in spans if s[0] == ROOT_SPAN
+    """The last `simulate` (or `simulate_sweep`) span: its seconds, its
+    children's seconds, and its counters; None when the trace holds none
+    that carries them. `simulate_s` keeps its name for either root."""
+    roots = [s for s in spans if s[0] in ROOT_SPANS
              and all(k in s[3] for k in COUNTERS)]
     if not roots:
         return None
@@ -280,7 +293,8 @@ def _plan(run):
     """The run's answer, built again: the harness has let its own go."""
     from bench import cells
     return _once(run, "plan",
-                 lambda: cells.build(run.cell, cells.program_modules()))
+                 lambda: cells.build(run.cell, cells.program_modules(),
+                                     devices=getattr(run, "devices", None)))
 
 
 def _device_trace_scopes(run) -> dict | None:

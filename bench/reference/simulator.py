@@ -1288,3 +1288,16 @@ def simulate(workload, mesh: topo.MeshTopology, cfg: SimConfig | None = None,
     return _finalize(state, tr, ticks, iters, mesh, scfg)
 
 
+def simulate_sweep(workload, mesh: topo.MeshTopology, cfg: SimConfig,
+                   params_list, devices=None, **kwargs) -> list[SimResult]:
+    """A grid of points, one `simulate` call each, in order: every point
+    (a `SimConfig` sharing `cfg`'s static half) is stepped on its own, one
+    tick per loop iteration, on JAX's default device; `devices` is taken
+    for the program's signature and not used. `kwargs` are `simulate`'s
+    schedules and tables, shared by every point."""
+    out = []
+    for p in params_list:
+        if p.static != cfg.static:
+            raise ValueError("a sweep's points share one static config")
+        out.append(simulate(workload, mesh, p, **kwargs))
+    return out

@@ -41,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 from functools import lru_cache
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -154,9 +155,74 @@ class FibWorkload:
         return work(self.n)
 
 
-# --------------------------------------------------------------------------- #
-# Host-side mirrors of the in-graph sampling (used by test oracles)
-# --------------------------------------------------------------------------- #
+@dataclasses.dataclass(frozen=True)
+class UtsWorkload:
+    """UTS geometric tree, linear branching decay b(d) = b0·(1 − d/d_max).
+
+    The child count of a node at depth d with hash-uniform u ∈ (0,1] is
+    floor(log u / log q_d) with q_d = b(d)/(1 + b(d)) (geometric with mean
+    b(d)), capped at CHILD_CAP; the i-th child's seed is
+    `child_seed(seed, i)`. Every node costs one work unit and adds 1 to the
+    result checksum."""
+
+    b0: float = 4.0
+    d_max: int = 10
+    root_seed: int = 19
+
+    def root_task(self) -> np.ndarray:
+        return np.array([KIND_UTS, 0, self.root_seed, 0], dtype=np.int32)
+
+    def tables(self):
+        return {
+            "fib_mod": jnp.asarray(fib_mod_table()),
+            "fib_cost": jnp.ones(95, dtype=jnp.int32),
+            "fib_cutoff": jnp.int32(0),
+            "uts_b0": jnp.float32(self.b0),
+            "uts_dmax": jnp.int32(self.d_max),
+            "uts_logq": jnp.float32(0.0),
+        }
+
+    # ---- closed form: the tree enumerated level by level on the host ---- #
+    def expected_nodes(self, max_nodes: int = 50_000_000) -> int:
+        """Exact node count: a breadth-first walk on the host whose child
+        counts and seeds come from `_uts_child_count` and `child_seed`,
+        evaluated on JAX's default device (the platform's float32 `log`
+        and divide decide boundary children). Each level is padded to a
+        power of two with depth `d_max` rows, which have no children, so
+        the walk compiles once per padded size."""
+        depths = np.zeros(1, np.int32)
+        seeds = np.asarray([self.root_seed], np.int32)
+        b0, d_max = jnp.float32(self.b0), jnp.int32(self.d_max)
+        n = 0
+        while seeds.size:
+            n += seeds.size
+            if n > max_nodes:
+                raise RuntimeError(f"UTS tree larger than {max_nodes} nodes")
+            pad = _bucket(seeds.size) - seeds.size
+            ms = np.asarray(_counts_jit(
+                jnp.asarray(np.pad(depths, (0, pad),
+                                   constant_values=self.d_max)),
+                jnp.asarray(np.pad(seeds, (0, pad))), b0, d_max))[:seeds.size]
+            total = int(ms.sum())
+            if total == 0:
+                break
+            parent = np.repeat(np.arange(seeds.size), ms)
+            child_ix = np.arange(total) - np.repeat(np.cumsum(ms) - ms, ms)
+            pad = _bucket(total) - total
+            seeds = np.asarray(_child_seed_jit(
+                jnp.asarray(np.pad(seeds[parent], (0, pad))),
+                jnp.asarray(np.pad(child_ix.astype(np.int32), (0, pad)))
+            ))[:total]
+            depths = depths[parent] + 1
+        return n
+
+    def expected_result(self) -> int:
+        """Every node adds 1 to the checksum mod RESULT_MOD."""
+        return self.expected_nodes() % int(RESULT_MOD)
+
+
+def _bucket(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
 
 
 # --------------------------------------------------------------------------- #
@@ -173,6 +239,10 @@ def _uts_child_count(depth, seed, b0, d_max):
     m = jnp.floor(jnp.log(jnp.maximum(u, 1e-38)) / jnp.log(safe_q)).astype(jnp.int32)
     m = jnp.clip(m, 0, CHILD_CAP)
     return jnp.where((depth >= d_max) | (b_d <= 0.0), 0, m)
+
+
+_counts_jit = jax.jit(_uts_child_count)
+_child_seed_jit = jax.jit(child_seed)
 
 
 def expand(task, active, tables):

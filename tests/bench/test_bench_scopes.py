@@ -177,6 +177,15 @@ def test_host_spans_give_host_share_and_counters():
             + 100 * c["iterations"] / c["ticks"]) == pytest.approx(100.0)
 
 
+def test_a_sweep_root_span_reads_as_simulate():
+    """`simulate_sweep` writes the same children under its own root span,
+    with the counters summed over its points."""
+    spans = [[("simulate_sweep" if s[0] == "simulate" else s[0]), *s[1:]]
+             for s in FIXTURE["spans"]]
+    assert any(s[0] == "simulate_sweep" for s in spans)
+    assert pt.host_summary(spans) == pt.host_summary(FIXTURE["spans"])
+
+
 def test_metrics_absent_without_scope_span_or_replay():
     no_scope = {"devices": FIXTURE["devices"],
                 "paths": {k: "jit(f)/while/body/x" for k in FIXTURE["paths"]}}

@@ -183,6 +183,39 @@ def test_kernel_bytes_by_hand():
     assert peaks.roofline_share(1e6, 0.0, "TPU v5 lite") is None
 
 
+def _shapes(points, chips=1):
+    """`kernel_shapes` of a W=1584 plan: `simulate` (points None) or a
+    sweep of `points` on `chips`."""
+    plan = SimpleNamespace(mesh=SimpleNamespace(num_workers=1584),
+                           points=points,
+                           sweep_devices=lambda: [None] * chips)
+    return harness.kernel_shapes(plan)
+
+
+@pytest.mark.parametrize("reader", ["steal_compact_roofline",
+                                    "deque_apply_roofline"])
+def test_a_one_point_sweep_needs_the_bytes_of_simulate(reader):
+    """Under vmap one kernel call serves every point on its chip: a
+    one-point sweep reads as `simulate` does, eight points on one chip
+    need eight times the bytes, and on four chips two per call."""
+    trace = {"kernels": {reader[:-len("_roofline")]:
+                         {"count": 10, "device_s": 1e-3}}}
+    grid = [("global", 1)] * 8
+
+    def read(shapes):
+        run = _run([_answer(0.0, 1.0, 1, 1)], trace=trace, shapes=shapes)
+        return harness.metric_reader(reader)(run)
+
+    alone = read(_shapes(None))
+    assert _shapes(grid[:1]) == _shapes(None)
+    assert read(_shapes(grid[:1])) == alone
+    assert read(_shapes(grid)) == pytest.approx(8 * alone)
+    assert read(_shapes(grid, chips=4)) == pytest.approx(2 * alone)
+    # deque_apply: 1584 workers x 184 B x 10 calls in 1 ms over 819 GB/s
+    if reader == "deque_apply_roofline":
+        assert alone == pytest.approx(100 * 1584 * 184 * 10 / 819e9 / 1e-3)
+
+
 def _answer(t0, t1, ticks, events):
     return harness.Answer(seed=0, t0=t0, t1=t1, result=None, ticks=ticks,
                           events=events)
